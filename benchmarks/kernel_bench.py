@@ -188,6 +188,7 @@ def run_fused(dtype: str = "float32", smoke: bool = False) -> None:
     # honest one.)
     from repro.kernels.flash_decode import (flash_decode_oproj,
                                             oproj_hbm_bytes,
+                                            page_pool_shape,
                                             paged_attention_oproj_ref)
     hkv, g_d, hd, E = (2, 2, 16, 64) if smoke else (2, 4, 32, 256)
     seq = 32 if smoke else 128
@@ -195,8 +196,9 @@ def run_fused(dtype: str = "float32", smoke: bool = False) -> None:
     page = sched.tiles[0]
     nb = seq // page
     q = jnp.asarray(rng.normal(size=(1, hkv, g_d, hd)), jdt)
-    kp = jnp.asarray(rng.normal(size=(nb + 1, page, hkv, hd)), jdt)
-    vp = jnp.asarray(rng.normal(size=(nb + 1, page, hkv, hd)), jdt)
+    pool = page_pool_shape(nb + 1, hkv, page, hd)
+    kp = jnp.asarray(rng.normal(size=pool), jdt)
+    vp = jnp.asarray(rng.normal(size=pool), jdt)
     bt = jnp.asarray(1 + rng.permutation(nb).reshape(1, nb), jnp.int32)
     lengths = jnp.asarray([seq - 3], jnp.int32)
     wo = jnp.asarray(rng.normal(size=(hkv, g_d * hd, E)) * 0.1, jdt)

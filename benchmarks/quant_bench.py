@@ -73,6 +73,7 @@ def bench_matmul_w8(dims: tuple[int, int, int]) -> None:
 
 def bench_flash_decode_fp8(dims: tuple[int, int, int]) -> None:
     from repro.kernels.flash_decode import (flash_decode, flash_decode_fp8,
+                                            page_pool_shape,
                                             paged_attention_fp8_ref)
     G, S, D = dims
     rng = np.random.default_rng(1)
@@ -83,8 +84,9 @@ def bench_flash_decode_fp8(dims: tuple[int, int, int]) -> None:
 
     def make_pool(page, dtype):
         nb = -(-S // page)
-        kp = jnp.asarray(rng.normal(size=(nb + 1, page, 1, D)), dtype)
-        vp = jnp.asarray(rng.normal(size=(nb + 1, page, 1, D)), dtype)
+        pool = page_pool_shape(nb + 1, 1, page, D)
+        kp = jnp.asarray(rng.normal(size=pool), dtype)
+        vp = jnp.asarray(rng.normal(size=pool), dtype)
         bt = jnp.asarray(1 + rng.permutation(nb)[None, :], jnp.int32)
         return kp, vp, bt
 

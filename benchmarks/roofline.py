@@ -20,13 +20,16 @@ import os
 
 from benchmarks.common import emit
 from repro.configs import ARCHS, SHAPES, cells, get_config
-from repro.core import TPU_V5E
+from repro.core import target_for
 from repro.models.config import ModelConfig
 
-# hardware model shared with the kernel profiler (repro.core.TPU_V5E)
-PEAK_FLOPS = TPU_V5E.peak_bf16_flops
-HBM_BW = TPU_V5E.hbm_bytes_per_s
-LINK_BW = TPU_V5E.ici_bytes_per_s_per_link
+# the dry-run cells model a v5e pod: peaks from the one device-kind table
+# the kernel profiler reads too (repro.core.DEVICE_TARGETS)
+DEVICE_KIND = "TPU v5 lite"
+_TARGET = target_for(DEVICE_KIND)
+PEAK_FLOPS = _TARGET.peak_bf16_flops
+HBM_BW = _TARGET.hbm_bytes_per_s
+LINK_BW = _TARGET.ici_bytes_per_s_per_link
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                        "dryrun")
 

@@ -1,4 +1,4 @@
-"""granite-3-8b: dense, GQA kv=8 [hf:ibm-granite/granite-3.0-2b-base]."""
+"""granite-3-8b: dense, GQA kv=8 [hf:ibm-granite/granite-3.0-8b-base]."""
 
 from repro.models.config import ModelConfig
 

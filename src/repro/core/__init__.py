@@ -30,10 +30,11 @@ from repro.core.fusion import (Epilogue, FusedProblem, FusedTraffic,
 from repro.core.gemm_lowering import (direct_blocking_accesses,
                                       gemm_lowering_accesses,
                                       lowered_gemm_problem)
-from repro.core.tpu_adapter import (TPU_V5E, TpuTarget,
+from repro.core.tpu_adapter import (DEVICE_TARGETS, TPU_V5E, TpuTarget,
                                     conv_tile_candidates, conv_tiles,
                                     flash_tiles, layer_sharding_advice,
-                                    matmul_tile_candidates, matmul_tiles)
+                                    matmul_tile_candidates, matmul_tiles,
+                                    target_for)
 
 __all__ = [
     "BlockingString", "Dim", "Extents", "Loop", "Problem", "divisors",
@@ -51,7 +52,7 @@ __all__ = [
     "fused_energy_pj", "fused_multicore_dram_bytes", "optimize_fused",
     "direct_blocking_accesses", "gemm_lowering_accesses",
     "lowered_gemm_problem",
-    "TPU_V5E", "TpuTarget", "conv_tile_candidates", "conv_tiles",
-    "flash_tiles", "layer_sharding_advice", "matmul_tile_candidates",
-    "matmul_tiles",
+    "DEVICE_TARGETS", "TPU_V5E", "TpuTarget", "conv_tile_candidates",
+    "conv_tiles", "flash_tiles", "layer_sharding_advice",
+    "matmul_tile_candidates", "matmul_tiles", "target_for",
 ]

@@ -49,6 +49,22 @@ TPU_V5E = TpuTarget(
     ici_bytes_per_s_per_link=50e9,
 )
 
+# The one table of per-chip peaks, keyed by ``jax.Device.device_kind``
+# (source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s).  A kind missing here is an error, not a
+# default: a roofline priced against another chip's peaks is wrong.
+DEVICE_TARGETS: dict[str, TpuTarget] = {"TPU v5 lite": TPU_V5E}
+
+
+def target_for(device_kind: str) -> TpuTarget:
+    """The published peaks of the chip JAX reports as ``device_kind``."""
+    try:
+        return DEVICE_TARGETS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_TARGETS)}") from None
+
 
 def default_vmem_budget(target: TpuTarget = TPU_V5E,
                         vmem_budget_bytes: int | None = None) -> int:
@@ -99,13 +115,14 @@ def _snap_matmul(bm: int, bk: int, bn: int, M: int, N: int, K: int,
     bk = _pick_tile(K, max(bk, target.lane), target.lane)
     while not _matmul_fits(bm, bk, bn, bytes_per_elem, budget,
                            weight_bytes):
-        # shrink the largest contributor
+        # shrink the largest contributor, re-snapped: halving an aligned
+        # divisor (12800 -> 800) can leave the alignment Mosaic needs
         if bk * (bm + bn) >= bm * bn and bk > target.lane:
-            bk = max(target.lane, bk // 2)
+            bk = _pick_tile(K, max(target.lane, bk // 2), target.lane)
         elif bm >= bn and bm > target.sublane:
-            bm = max(target.sublane, bm // 2)
+            bm = _pick_tile(M, max(target.sublane, bm // 2), target.sublane)
         elif bn > target.lane:
-            bn = max(target.lane, bn // 2)
+            bn = _pick_tile(N, max(target.lane, bn // 2), target.lane)
         else:
             break
     return bm, bk, bn
@@ -303,6 +320,8 @@ def flash_decode_tile_candidates(groups: int, seq_kv: int, head_dim: int,
     raw.append(min(seq_kv, 512))                 # heuristic fallback seed
     out: list[tuple[int]] = []
     for bkv in raw:
+        # the page is the sublane dim of the kernel's (page, D) block:
+        # lane multiples also meet the fp8 pool's 32-row packing
         mult = target.lane if seq_kv >= target.lane else 1
         bkv = _pick_tile(seq_kv, max(bkv, mult), mult)
         while (vmem_bytes_required(bkv, groups, head_dim, bytes_per_elem,

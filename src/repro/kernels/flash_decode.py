@@ -12,7 +12,9 @@ come from the same analytical blocking model.
 Layouts (GQA-native: all G query heads of one KV head share its pages):
 
 * ``q``:            (B, Hkv, G, D) — the current token's query rows;
-* ``k/v_pages``:    (n_pages, page, Hkv, D) — the global page pool;
+* ``k/v_pages``:    (n_pages, Hkv, page, D) — the global page pool, head-
+  major inside a page so one kernel block ``(1, 1, page, D)`` is a
+  contiguous (sublane x lane) tile of one KV head;
 * ``block_tables``: (B, n_blocks) int32 — physical page of each logical
   KV block; entries past a request's length must still be *valid* page
   indices (use 0) because the DMA runs before the mask is applied;
@@ -37,16 +39,32 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.flash_attention import NEG_INF
 
 
+LANE, SUBLANE = 128, 8
+
+
+def _pad(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
+def page_pool_shape(n_pages: int, hkv: int, page: int,
+                    head_dim: int) -> tuple[int, int, int, int]:
+    """Shape of a K or V page pool as every kernel here reads it."""
+    return (n_pages, hkv, page, head_dim)
+
+
 def vmem_bytes_required(block_kv: int, groups: int, head_dim: int,
                         bytes_per_elem: int = 2,
                         kv_bytes: int | None = None,
                         q_span: int = 1) -> int:
     """VMEM footprint of one grid step of :func:`flash_decode`.
 
-    The K and V pages are streamed (Pallas double-buffers them across
-    grid steps, hence the factor 2); the query tile, the output tile and
-    the fp32 (m, l, acc) running statistics stay resident; the score
-    block is fp32 intermediate.  Single source of truth for the
+    Counts what Mosaic allocates, not just the logical tiles: every
+    pipelined block (K and V pages, the q block and the output block)
+    is double-buffered across grid steps; the (rows, 1) fp32 running
+    max/denominator scratch pads to a full 128-lane row each; the kernel
+    body keeps fp32 copies of q, K and V next to the fp32 score block
+    ``s`` and its exponentials ``p``.  Minor dims pad to lanes and row
+    counts to sublanes.  Single source of truth for the
     ``"flash_decode"`` schedule-candidate filter in ``tune.lowering``.
 
     ``kv_bytes`` is the page element width when the cache is quantized
@@ -55,19 +73,21 @@ def vmem_bytes_required(block_kv: int, groups: int, head_dim: int,
 
     ``q_span`` is the number of query *positions* folded into the q
     block (speculative verify / chunked prefill): everything that scales
-    with the query rows — q/o tiles, scores, running stats — multiplies
+    with the query rows — q/o blocks, scores, running stats — multiplies
     by it, while the streamed pages do not.  That asymmetry is what lets
     ``serve.kv_cache.choose_prefill_chunk`` price a multi-page chunk
     against the same VMEM budget the page size was tuned under.
     """
     kvb = kv_bytes or bytes_per_elem
-    rows = groups * q_span
-    streamed = 2 * 2 * block_kv * head_dim * kvb                # K + V pages
-    q_tile = rows * head_dim * bytes_per_elem
-    o_tile = rows * head_dim * bytes_per_elem
-    scores = rows * block_kv * 4
-    acc = rows * head_dim * 4 + 2 * rows * 4                    # acc, m, l
-    return streamed + q_tile + o_tile + scores + acc
+    rows = _pad(groups * q_span, SUBLANE)
+    d = _pad(head_dim, LANE)
+    kv_cols = _pad(block_kv, LANE)
+    streamed = 2 * 2 * block_kv * d * kvb                 # K + V, 2 buffers
+    q_o = 2 * 2 * rows * d * bytes_per_elem               # q + o, 2 buffers
+    stats = 2 * rows * LANE * 4 + rows * d * 4            # m, l; acc
+    f32_copies = (rows + 2 * block_kv) * d * 4            # q, K, V in fp32
+    scores = 2 * rows * kv_cols * 4                       # s and p
+    return streamed + q_o + stats + f32_copies + scores
 
 
 def _block_mask(len_ref, b, i, block_kv: int, window: int | None,
@@ -144,8 +164,8 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     _decode_init(i, m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                  # (q_span*G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, D)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, D)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (bkv, D)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
@@ -170,10 +190,11 @@ def _decode_fp8_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
     _decode_init(i, m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, D) fp8->f32
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    ks = ks_ref[0, 0]                                    # this head's scales
-    vs = vs_ref[0, 0]
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, D) fp8->f32
+    v = v_ref[0, 0].astype(jnp.float32)
+    h = pl.program_id(1)
+    ks = ks_ref[h]                                       # this head's scales
+    vs = vs_ref[h]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (scale * ks)
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
@@ -208,7 +229,7 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if gtot % q_span:
         raise ValueError(f"q rows {gtot} not divisible by q_span {q_span}")
     g = gtot // q_span
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     n_blocks = block_tables.shape[1]
     scale = d ** -0.5
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -217,10 +238,10 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, gtot, d),
                          lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, gtot, d),
                                lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
@@ -266,23 +287,25 @@ def flash_decode_fp8(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if gtot % q_span:
         raise ValueError(f"q rows {gtot} not divisible by q_span {q_span}")
     g = gtot // q_span
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     n_blocks = block_tables.shape[1]
     scale = d ** -0.5
-    ks = jnp.asarray(k_scale, jnp.float32).reshape(hkv, 1)
-    vs = jnp.asarray(v_scale, jnp.float32).reshape(hkv, 1)
+    ks = jnp.asarray(k_scale, jnp.float32).reshape(hkv)
+    vs = jnp.asarray(v_scale, jnp.float32).reshape(hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, n_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, gtot, d),
                          lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, i, bt, ln: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, i, bt, ln: (h, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            # the (Hkv,) scale vectors sit whole in SMEM: a per-head
+            # (1, 1) VMEM block would split the sublane dim
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, gtot, d),
                                lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
@@ -314,7 +337,7 @@ def hbm_bytes(batch: int, hkv: int, groups: int, head_dim: int,
     KV-block grid dim, so each moves once per (batch, kv-head) row; the
     K/V pages stream once per row.  ``kv_bytes`` gives the paged K/V
     streams their own width (fp8 cache: 1); the fp8 variant additionally
-    fetches the two per-head fp32 dequant scales once per row change.
+    loads the two (Hkv,) fp32 dequant-scale vectors into SMEM once.
     """
     nb = -(-seq // block_kv)
     kvb = bytes_per_elem if kv_bytes is None else kv_bytes
@@ -323,8 +346,7 @@ def hbm_bytes(batch: int, hkv: int, groups: int, head_dim: int,
     out = batch * hkv * groups * head_dim * bytes_per_elem
     total = q_bytes + kv + out
     if kv_bytes is not None:
-        # (h, 0)-indexed scale scalars: refetched when h changes
-        total += 2 * 4 * (batch * hkv if hkv > 1 else 1)
+        total += 2 * 4 * hkv              # whole scale vectors, once
     return total
 
 
@@ -332,14 +354,17 @@ def oproj_vmem_bytes_required(block_kv: int, groups: int, head_dim: int,
                               d_model: int,
                               bytes_per_elem: int = 2) -> int:
     """VMEM footprint of one grid step of :func:`flash_decode_oproj`:
-    the base decode footprint plus the streamed per-head wo slab
-    (G*D x E) and the fp32 (1, E) output accumulator that stays
+    the base decode footprint plus the double-buffered per-head wo slab
+    (G*D x E), the fp32 copy of one group's (D, E) slice, and the
+    sublane-padded (1, E) fp32 accumulator and output block that stay
     resident across the head loop.  Single source of truth for the
     ``"flash_decode_oproj"`` schedule-candidate filter."""
     base = vmem_bytes_required(block_kv, groups, head_dim, bytes_per_elem)
-    wo_slab = 2 * groups * head_dim * d_model * bytes_per_elem
-    out_acc = d_model * 4 + d_model * bytes_per_elem
-    return base + wo_slab + out_acc
+    e = _pad(d_model, LANE)
+    wo_slab = 2 * groups * head_dim * e * bytes_per_elem
+    wo_f32 = head_dim * e * 4
+    out = SUBLANE * e * 4 + 2 * SUBLANE * e * bytes_per_elem
+    return base + wo_slab + wo_f32 + out
 
 
 def oproj_hbm_bytes(batch: int, hkv: int, groups: int, head_dim: int,
@@ -361,7 +386,8 @@ def _decode_oproj_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, wo_ref,
                          o_ref, m_ref, l_ref, acc_ref, oacc_ref, *,
                          scale: float, window: int | None,
                          logit_cap: float | None, block_kv: int,
-                         n_blocks: int, n_heads: int):
+                         n_blocks: int, n_heads: int, groups: int,
+                         head_dim: int):
     """Flash-decode with the output projection's row tile fused in.
 
     Grid is (B, Hkv, n_blocks) with the KV block minor-most, exactly as
@@ -382,8 +408,8 @@ def _decode_oproj_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, wo_ref,
         oacc_ref[...] = jnp.zeros_like(oacc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
@@ -396,13 +422,18 @@ def _decode_oproj_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, wo_ref,
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         attn = (acc_ref[...] / safe_l)                   # (G, D) fp32
-        wo = wo_ref[0].astype(jnp.float32)               # (G*D, E)
-        oacc_ref[...] += jnp.dot(attn.reshape(1, -1), wo,
-                                 preferred_element_type=jnp.float32)
+        # row g of attn meets rows [g*D, (g+1)*D) of the (G*D, E) slab;
+        # static per-group slices keep every operand a 2-D tile
+        out = oacc_ref[...]
+        for gi in range(groups):
+            wo = wo_ref[0, gi * head_dim:(gi + 1) * head_dim, :]
+            out += jnp.dot(attn[gi:gi + 1, :], wo.astype(jnp.float32),
+                           preferred_element_type=jnp.float32)
+        oacc_ref[...] = out
 
     @pl.when((h == n_heads - 1) & (i == n_blocks - 1))
     def _done():
-        o_ref[...] = oacc_ref[...].astype(o_ref.dtype)
+        o_ref[0] = oacc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "logit_cap",
@@ -433,7 +464,7 @@ def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
     ``oproj_hbm_bytes`` exposes — leave ``fuse`` off there.
     """
     b, hkv, g, d = q.shape
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     e = wo.shape[-1]
     assert wo.shape == (hkv, g * d, e), (wo.shape, (hkv, g * d, e))
     n_blocks = block_tables.shape[1]
@@ -443,13 +474,16 @@ def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
         grid=(b, hkv, n_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
             pl.BlockSpec((1, g * d, e), lambda bi, h, i, bt, ln: (h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, e), lambda bi, h, i, bt, ln: (bi, 0)),
+        # (B, 1, E) so the block's last two dims are whole: a (1, E)
+        # block over (B, E) would split the sublane dim
+        out_specs=pl.BlockSpec((1, 1, e),
+                               lambda bi, h, i, bt, ln: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),     # running max m
             pltpu.VMEM((g, 1), jnp.float32),     # running denom l
@@ -460,12 +494,13 @@ def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
     return pl.pallas_call(
         functools.partial(_decode_oproj_kernel, scale=scale, window=window,
                           logit_cap=logit_cap, block_kv=page,
-                          n_blocks=n_blocks, n_heads=hkv),
+                          n_blocks=n_blocks, n_heads=hkv, groups=g,
+                          head_dim=d),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, e), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages, wo)
+      q, k_pages, v_pages, wo).reshape(b, e)
 
 
 def paged_attention_oproj_ref(q: jax.Array, k_pages: jax.Array,
@@ -498,9 +533,9 @@ def paged_attention_fp8_ref(q: jax.Array, k_pages: jax.Array,
     """jnp oracle for :func:`flash_decode_fp8`: dequantize the page pool
     in fp32, then the dense masked softmax of :func:`paged_attention_ref`.
     """
-    hkv = k_pages.shape[2]
-    ks = jnp.asarray(k_scale, jnp.float32).reshape(1, 1, hkv, 1)
-    vs = jnp.asarray(v_scale, jnp.float32).reshape(1, 1, hkv, 1)
+    hkv = k_pages.shape[1]
+    ks = jnp.asarray(k_scale, jnp.float32).reshape(1, hkv, 1, 1)
+    vs = jnp.asarray(v_scale, jnp.float32).reshape(1, hkv, 1, 1)
     return paged_attention_ref(q, k_pages.astype(jnp.float32) * ks,
                                v_pages.astype(jnp.float32) * vs,
                                block_tables, lengths, window=window,
@@ -522,11 +557,14 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     """
     b, hkv, gtot, d = q.shape
     g = gtot // q_span
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     nb = block_tables.shape[1]
-    k = k_pages[block_tables].reshape(b, nb * page, hkv, d)
-    v = v_pages[block_tables].reshape(b, nb * page, hkv, d)
-    s = jnp.einsum("bhgd,blhd->bhgl", q.astype(jnp.float32),
+    # (b, nb, hkv, page, d) -> (b, hkv, nb * page, d)
+    k = k_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, nb * page, d)
+    v = v_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, nb * page, d)
+    s = jnp.einsum("bhgd,bhld->bhgl", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * d ** -0.5
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
@@ -538,5 +576,5 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
         valid &= kpos[None, None, :] > (lim[..., None] - 1) - window
     s = jnp.where(valid[:, None, :, :], s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgl,blhd->bhgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bhgl,bhld->bhgd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)

@@ -328,7 +328,7 @@ def paged_attention_oproj(q: jax.Array, k_pages: jax.Array,
                                             paged_attention_oproj_ref)
     from repro.quant.quantize import QuantizedTensor
     b, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     fp8 = jnp.dtype(k_pages.dtype).itemsize == 1
@@ -484,7 +484,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         use_kernel = False
 
     groups = hq // hkv
-    qg = q.reshape(b, sq, hkv, groups, d)
+    # batch, kv-head and group lead, so the vmapped kernel's blocks keep
+    # (seq, D) as their last two dims — the (sublane, lane) tile
+    qg = q.reshape(b, sq, hkv, groups, d).transpose(0, 2, 3, 1, 4)
 
     def one_head(qh, kh, vh):  # (Sq, D), (Skv, D), (Skv, D)
         if use_kernel:
@@ -498,17 +500,15 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return ref.attention_ref(qh, kh, vh, causal=causal,
                                  logit_cap=logit_cap, window=window)
 
-    def per_kvhead(qh, kh, vh):  # qh: (Sq, G, D); kh, vh: (Skv, D)
-        return jax.vmap(lambda qx: one_head(qx, kh, vh),
-                        in_axes=1, out_axes=1)(qh)       # (Sq, G, D)
+    def per_kvhead(qh, kh, vh):  # qh: (G, Sq, D); kh, vh: (Skv, D)
+        return jax.vmap(lambda qx: one_head(qx, kh, vh))(qh)
 
-    # vmap over kv-heads (inner) and batch (outer)
+    # vmap over groups (innermost), kv-heads and batch (outer)
     fn = jax.vmap(jax.vmap(per_kvhead))
-    out = fn(qg.transpose(0, 2, 1, 3, 4),   # (B, Hkv, Sq, G, D)
+    out = fn(qg,                            # (B, Hkv, G, Sq, D)
              k.transpose(0, 2, 1, 3),       # (B, Hkv, Skv, D)
-             v.transpose(0, 2, 1, 3))       # -> (B, Hkv, Sq, G, D)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(b, sq, hq, d)
-    return out
+             v.transpose(0, 2, 1, 3))       # -> (B, Hkv, G, Sq, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -522,7 +522,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Single-token attention over a paged KV cache (decode path).
 
     q: (B, Hq, D) — the current token's query rows; k/v_pages:
-    (n_pages, page, Hkv, D); block_tables: (B, n_blocks) physical page
+    (n_pages, Hkv, page, D); block_tables: (B, n_blocks) physical page
     per logical KV block; lengths: (B,) cache length per request
     *including* the token being decoded.  Returns (B, Hq, D).
 
@@ -558,7 +558,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     else:
         b, hq, d = q.shape
         span = 1
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     if multi:

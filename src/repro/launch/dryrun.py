@@ -76,7 +76,7 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
 def _lower_compile(cfg, shape_name, mesh, parallelism="tp_fsdp"):
     t0 = time.time()
     low = input_specs(cfg, shape_name, mesh, parallelism=parallelism)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(low.fn, in_shardings=low.in_shardings,
                          out_shardings=low.out_shardings)
         lowered = jitted.lower(*low.args_shapes)

@@ -62,11 +62,9 @@ def plan_mesh(cfg: ModelConfig, n_devices: int,
 
 
 def make_elastic_mesh(plan: MeshPlan):
-    devs = jax.devices()[:plan.n_devices]
-    import numpy as np
-    arr = np.array(devs).reshape(plan.shape)
-    from jax.sharding import Mesh
-    return Mesh(arr, ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(plan.shape, ("data", "model"),
+                     devices=jax.devices()[:plan.n_devices])
 
 
 def reshard_checkpoint(cfg: ModelConfig, ckpt_dir: str, plan: MeshPlan):

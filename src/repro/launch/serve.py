@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.sharding import set_axis_mapping
 from repro.obs import Obs, format_metrics
@@ -111,6 +112,7 @@ def main() -> None:
                          "--from-telemetry")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.quantize in ("fp8kv", "w8fp8"):
         cfg = dataclasses.replace(cfg,

@@ -16,14 +16,30 @@ import argparse
 import dataclasses
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.models.sharding import set_axis_mapping
+from repro.models import transformer as T
+from repro.models.config import ModelConfig
+from repro.models.sharding import set_axis_mapping, translate_tree
 from repro.obs import Obs, format_metrics
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainConfig, train
+
+
+def shard_params(cfg: ModelConfig, mesh, rng: jax.Array):
+    """Initialize parameters straight into their shardings on ``mesh``
+    (the installed axis mapping translates the model's canonical specs),
+    so no device ever holds the whole model."""
+    model_ax = dict(mesh.shape).get("model", 1)
+    specs = translate_tree(T.param_specs(cfg, model_ax))
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(lambda k: T.init_params(cfg, k, model_ax),
+                   out_shardings=shardings)(rng)
 
 
 def main() -> None:
@@ -59,6 +75,7 @@ def main() -> None:
                          "targets (meaningful with --blocked-kernels)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_production_mesh() if args.production_mesh \
         else make_host_mesh()
@@ -79,9 +96,10 @@ def main() -> None:
             yield make_batch(cfg, args.seq_len, args.batch, step)
 
     obs = Obs(trace=args.trace, miss_log=args.miss_log)
-    with mesh:
-        result = train(cfg, tc, batches(), restore=args.restore == "auto",
-                       obs=obs)
+    with jax.set_mesh(mesh):
+        params = shard_params(cfg, mesh, jax.random.PRNGKey(0))
+        result = train(cfg, tc, batches(), params=params,
+                       restore=args.restore == "auto", obs=obs)
     print(f"final loss: {result['history'][-1]:.4f} "
           f"(start {result['history'][0]:.4f})")
     if args.metrics_out:

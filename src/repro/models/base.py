@@ -14,6 +14,7 @@ keeping shapes and shardings impossible to de-synchronize.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -34,13 +35,20 @@ def fan_in_scale(fan_in: int) -> float:
     return fan_in ** -0.5
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _normal_leaf(d: ParamDef, key: jax.Array) -> jax.Array:
+    # jitted so only the cast result is materialized: drawn eagerly, a
+    # stacked leaf would first exist whole in float32
+    return (jax.random.truncated_normal(key, -3, 3, d.shape, jnp.float32)
+            * d.scale).astype(d.dtype)
+
+
 def _init_leaf(d: ParamDef, key: jax.Array) -> jax.Array:
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":
         return jnp.ones(d.shape, d.dtype)
-    return (jax.random.truncated_normal(key, -3, 3, d.shape, jnp.float32)
-            * d.scale).astype(d.dtype)
+    return _normal_leaf(d, key)
 
 
 def build(tree: Any, mode: str, rng: jax.Array | None = None) -> Any:

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ops, ref
 from repro.models.base import ParamDef, fan_in_scale
 from repro.models.config import ModelConfig
-from repro.models.sharding import maybe_shard
+from repro.models.sharding import maybe_shard, on_mesh, translate_spec
 
 
 def _shard_if(dim: int, model_ax: int, axis: str = "model"):
@@ -94,6 +94,32 @@ def attention_defs(cfg: ModelConfig, model_ax: int) -> dict:
     }
 
 
+def mha(q: jax.Array, k: jax.Array, v: jax.Array, **kw) -> jax.Array:
+    """``ops.attention`` over (B, S, H, D) activations.  On a mesh it runs
+    per device, on each device's share of the batch ("data") and of the
+    KV heads ("model"): GSPMD cannot partition the compiled attention
+    kernel.  A dim its mesh axes do not divide stays whole."""
+    fn = functools.partial(ops.attention, **kw)
+    if not on_mesh():
+        return fn(q, k, v)
+    mesh = jax.sharding.get_abstract_mesh()
+    used: set = set()
+
+    def fit(axis, n: int):
+        names = axis if isinstance(axis, tuple) else (axis,)
+        names = tuple(a for a in names
+                      if a in mesh.axis_names and a not in used)
+        if not names or n % math.prod(mesh.shape[a] for a in names):
+            return None
+        used.update(names)
+        return names
+
+    batch_ax, head_ax = translate_spec(P("data", "model"))
+    spec = P(fit(batch_ax, q.shape[0]), None, fit(head_ax, k.shape[2]))
+    return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
 def attention_apply(cfg: ModelConfig, params: dict, x: jax.Array,
                     positions: jax.Array, *, causal: bool = True,
                     window: int | None = None,
@@ -122,8 +148,8 @@ def attention_apply(cfg: ModelConfig, params: dict, x: jax.Array,
         v = ops.linear(x, params["wv"]).reshape(b, s, hkv, hd)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    out = ops.attention(q, k, v, causal=causal, window=window,
-                        logit_cap=cfg.attn_logit_cap)
+    out = mha(q, k, v, causal=causal, window=window,
+              logit_cap=cfg.attn_logit_cap)
     out = ops.linear(out.reshape(b, s, hq * hd), params["wo"])
     if not return_cache:
         return out
@@ -341,11 +367,7 @@ class _ShardMapUnavailable(Exception):
 
 def _moe_apply_shardmap(cfg: ModelConfig, params: dict, x: jax.Array,
                         mapping: dict) -> tuple[jax.Array, jax.Array]:
-    from jax.experimental.shard_map import shard_map
-    from repro.models.sharding import translate_spec
-
-    env = jax.interpreters.pxla.thread_resources.env
-    mesh = env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size <= 1:
         raise _ShardMapUnavailable()
     ma = mapping["model"]
@@ -423,12 +445,12 @@ def _moe_apply_shardmap(cfg: ModelConfig, params: dict, x: jax.Array,
                                  tiled=True)       # (tl, d)
         return out.reshape(bl, sl, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, w_specs["router"], w_specs["w_gate"],
                   w_specs["w_up"], w_specs["w_down"]),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, params["router"], params["w_gate"], params["w_up"],
               params["w_down"])
 

@@ -49,12 +49,9 @@ def translate_tree(tree: Any, mapping: dict[str, Any] | None = None) -> Any:
 
 
 def on_mesh() -> bool:
-    """True when running under a ``with mesh:`` context with >1 device."""
-    try:
-        env = jax.interpreters.pxla.thread_resources.env
-        return env.physical_mesh.size > 1
-    except Exception:
-        return False
+    """True under a ``jax.set_mesh(mesh)`` context with >1 device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return not mesh.empty and mesh.size > 1
 
 
 def maybe_shard(x: jax.Array, spec: P) -> jax.Array:

@@ -140,14 +140,13 @@ def _block_apply(cfg: ModelConfig, p: dict, h: jax.Array, mixer: str,
 
 
 def _cross_attention(cfg, p, x, enc_out, positions, enc_positions):
-    from repro.kernels import ops
     b, s, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     se = enc_out.shape[1]
     q = (x @ p["wq"]).reshape(b, s, hq, hd)
     k = (enc_out @ p["wk"]).reshape(b, se, hkv, hd)
     v = (enc_out @ p["wv"]).reshape(b, se, hkv, hd)
-    out = ops.attention(q, k, v, causal=False)
+    out = L.mha(q, k, v, causal=False)
     return out.reshape(b, s, hq * hd) @ p["wo"]
 
 
@@ -506,19 +505,26 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array,
                  axis=0) * (cfg.d_model ** 0.5)
     pattern = cfg.layer_pattern
 
-    def scan_step(h, xs):
-        cycle_params, cycle_cache = xs
-        new_caches = []
+    def scan_step(carry, xs):
+        # the stacked caches ride in the carry and each layer's slice is
+        # written back in place: as scan outputs they would be a second
+        # whole copy of every cache (pools are most of device memory)
+        h, caches = carry
+        cycle_params, i = xs
         for j, mixer in enumerate(pattern):
+            layer = jax.tree.map(lambda c: c[i], caches[j])
             h, nc = _block_decode(cfg, cycle_params[j], h, mixer,
-                                  cycle_cache[j], pos, attn_step)
-            new_caches.append(nc)
-        return h, new_caches
+                                  layer, pos, attn_step)
+            caches[j] = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                    c, n.astype(c.dtype), i, 0), caches[j], nc)
+        return (h, caches), None
 
     n_groups = cfg.n_layers // len(pattern)
     if n_groups:
-        h, new_layer_caches = _scan(
-            scan_step, h, (params["layers"], cache["layers"]))
+        (h, new_layer_caches), _ = _scan(
+            scan_step, (h, list(cache["layers"])),
+            (params["layers"], jnp.arange(n_groups)))
     else:
         new_layer_caches = cache["layers"]
     new_tail = []

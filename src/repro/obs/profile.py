@@ -18,8 +18,11 @@ derives:
   trace, not once per layer (resolutions fire at trace time);
 * **exact HBM bytes** — per-call ``hbm_bytes`` x dispatch count;
 * **achieved vs peak** — arithmetic intensity (2·MACs / bytes) against
-  the :data:`~repro.core.tpu_adapter.TPU_V5E` roofline, reporting the
-  achieved fraction of the intensity-limited ceiling;
+  the roofline of the chip this runs on (its ``device_kind`` in
+  ``core.tpu_adapter.DEVICE_TARGETS``), reporting the achieved fraction
+  of the intensity-limited ceiling.  On a CPU, where the kernels run in
+  interpret mode, there is no chip to price against: no share is
+  reported;
 * **energy** — the paper's model split (``obs.energy``): DRAM priced on
   the measured bytes, SRAM + MAC from the schedule's blocking string.
 
@@ -34,7 +37,7 @@ from __future__ import annotations
 
 import time
 
-from repro.core.tpu_adapter import TPU_V5E, TpuTarget
+from repro.core.tpu_adapter import target_for
 from repro.obs.dram import DramLedger
 from repro.obs.energy import op_energy_pj
 from repro.tune.schedule import OpSpec, Schedule
@@ -107,10 +110,13 @@ class KernelProfiler(DramLedger):
 
     def __init__(self, registry=None, miss_log: str | None = None,
                  fidelity_threshold: float = 0.25,
-                 target: TpuTarget = TPU_V5E, tracer=None):
+                 tracer=None):
+        import jax
         super().__init__(registry=registry, miss_log=miss_log)
         self.fidelity_threshold = fidelity_threshold
-        self.target = target
+        dev = jax.devices()[0]
+        self.target = (None if dev.platform == "cpu"
+                       else target_for(dev.device_kind))
         self.tracer = tracer
         self._wall_s: dict[str, float] = {}       # tag -> total scope wall
         self._tag_kbytes: dict[str, int] = {}     # tag -> kernel B / exec
@@ -218,7 +224,8 @@ class KernelProfiler(DramLedger):
     def roofline_report(self) -> dict:
         """JSON-safe roofline + energy report, one row per dispatched
         kernel variant.  ``peak_frac`` is achieved FLOP/s over the
-        intensity-limited ceiling min(peak, AI x HBM bandwidth)."""
+        intensity-limited ceiling min(peak, AI x HBM bandwidth); it and
+        ``bound`` are left out where there is no chip (``target`` None)."""
         t = self.target
         rows = {}
         totals = {"time_s": 0.0, "bytes": 0, "flops": 0,
@@ -248,10 +255,11 @@ class KernelProfiler(DramLedger):
             }
             if roll["time_s"] > 0 and ai is not None:
                 achieved = flops / roll["time_s"]
-                ceiling = min(t.peak_bf16_flops, ai * t.hbm_bytes_per_s)
                 row["achieved_gflops"] = round(achieved / 1e9, 2)
                 row["achieved_gbps"] = round(
                     roll["bytes"] / roll["time_s"] / 1e9, 2)
+            if roll["time_s"] > 0 and ai is not None and t is not None:
+                ceiling = min(t.peak_bf16_flops, ai * t.hbm_bytes_per_s)
                 row["peak_frac"] = round(achieved / ceiling, 4)
                 row["bound"] = ("memory" if ai * t.hbm_bytes_per_s
                                 < t.peak_bf16_flops else "compute")
@@ -263,9 +271,10 @@ class KernelProfiler(DramLedger):
             if energy_pj is not None:
                 totals["energy_pj"] += energy_pj
         return {
-            "target": {"name": t.name,
-                       "peak_bf16_flops": t.peak_bf16_flops,
-                       "hbm_bytes_per_s": t.hbm_bytes_per_s},
+            "target": (None if t is None else
+                       {"name": t.name,
+                        "peak_bf16_flops": t.peak_bf16_flops,
+                        "hbm_bytes_per_s": t.hbm_bytes_per_s}),
             "fidelity_threshold": self.fidelity_threshold,
             "fidelity_misses": sorted(self._fid_flagged),
             "per_op": rows,

@@ -19,8 +19,9 @@ the serve benchmark uses, with a :class:`repro.obs.KernelProfiler` in
 the ledger slot and a step tracer always attached (the engines fence
 every scope when a tracer is present, so scope wall clocks measure
 device time).  Every dispatched kernel variant gets measured wall time,
-exact HBM bytes from the kernels' own grid-transfer accounting, achieved
-vs peak arithmetic intensity on the TPU v5e roofline, and modeled pJ.
+exact HBM bytes from the kernels' own grid-transfer accounting,
+arithmetic intensity, modeled pJ and, on a TPU, the achieved share of
+that chip's roofline.
 """
 
 from __future__ import annotations

@@ -343,10 +343,14 @@ class PagedEngine:
         self._joins: dict[int, Any] = {}           # bucket -> jitted join
         self._chunk_fns: dict[int, Any] = {}       # span width -> chunk fn
         self._fork_fn: Any = None                  # jitted CoW page copy
+        # every device fn donates the page pools (argument 1): the step
+        # updates them in place instead of holding two copies
         self._decode = jax.jit(self._decode_fn,
-                               static_argnames=("chunk",))
+                               static_argnames=("chunk",),
+                               donate_argnums=(1,))
         self._decode_spec = jax.jit(self._decode_spec_fn,
-                                    static_argnames=("chunk",))
+                                    static_argnames=("chunk",),
+                                    donate_argnums=(1,))
         self.last_step_tokens = 0                  # benchmark counter
         # registry-backed counters (spec_stats/prefix_stats are views)
         self._m_steps = reg.counter("engine.steps")
@@ -779,7 +783,7 @@ class PagedEngine:
                     return out + (bad,)
                 return out
 
-            self._joins[bucket] = jax.jit(join)
+            self._joins[bucket] = jax.jit(join, donate_argnums=(1,))
         return self._joins[bucket]
 
     # -- prefix cache ---------------------------------------------------------
@@ -791,7 +795,7 @@ class PagedEngine:
         if self._fork_fn is None:
             def fork(cache, src, dst):
                 def cp(pc, stacked):
-                    if stacked:     # (n_groups, n_pages, page, hkv, hd)
+                    if stacked:     # (n_groups, n_pages, hkv, page, hd)
                         return {k: pc[k].at[:, dst].set(pc[k][:, src])
                                 for k in ("k_pages", "v_pages")}
                     return {k: pc[k].at[dst].set(pc[k][src])
@@ -897,7 +901,7 @@ class PagedEngine:
                     return cache, lengths, cur_tok, out_buf, hist, bad
                 return cache, lengths, cur_tok, out_buf, hist
 
-            self._chunk_fns[C] = jax.jit(chunk)
+            self._chunk_fns[C] = jax.jit(chunk, donate_argnums=(1,))
         return self._chunk_fns[C]
 
     # -- decode ---------------------------------------------------------------

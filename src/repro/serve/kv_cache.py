@@ -2,7 +2,7 @@
 
 The serving analogue of the paper's buffer-sizing rule: instead of one
 dense ``(B, max_seq, Hkv, D)`` ring buffer per request slot, every
-attention layer owns a global *page pool* ``(n_pages, page, Hkv, D)`` and
+attention layer owns a global *page pool* ``(n_pages, Hkv, page, D)`` and
 each request holds a block table mapping its logical KV blocks to
 physical pages.  The page size is not a heuristic — it is the KV block
 of the flash-decode kernel, chosen by the analytical blocking optimizer
@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.flash_decode import page_pool_shape
 from repro.models import layers as L
 from repro.models.base import ParamDef, build, stack_defs
 from repro.models.config import ModelConfig
@@ -176,11 +177,12 @@ def paged_attention_cache_defs(cfg: ModelConfig, n_pages: int,
     hkv, hd = cfg.n_kv_heads, cfg.head_dim
     cache_dtype = cfg.kv_cache_dtype or cfg.dtype
     skv = "model" if model_ax > 1 and hkv % model_ax == 0 else None
-    spec = P(None, None, skv, None)
-    return {"k_pages": ParamDef((n_pages, page_size, hkv, hd), spec,
-                                init="zeros", dtype=cache_dtype),
-            "v_pages": ParamDef((n_pages, page_size, hkv, hd), spec,
-                                init="zeros", dtype=cache_dtype)}
+    spec = P(None, skv, None, None)
+    shape = page_pool_shape(n_pages, hkv, page_size, hd)
+    return {"k_pages": ParamDef(shape, spec, init="zeros",
+                                dtype=cache_dtype),
+            "v_pages": ParamDef(shape, spec, init="zeros",
+                                dtype=cache_dtype)}
 
 
 def paged_cache_defs(cfg: ModelConfig, batch: int, n_pages: int,
@@ -238,10 +240,10 @@ def write_prefill(cfg: ModelConfig, paged: dict, dense: dict,
         nb = num_blocks(bucket, page_size)
         pad = nb * page_size - bucket
 
-        def scatter(pool, kv):          # (n_pages, p, hkv, hd), (bucket,...)
+        def scatter(pool, kv):          # (n_pages, hkv, p, hd), (bucket,...)
             blocks = jnp.pad(kv, ((0, pad), (0, 0), (0, 0))).reshape(
-                nb, page_size, *kv.shape[1:]).astype(pool.dtype)
-            return pool.at[pages[:nb]].set(blocks)
+                nb, page_size, *kv.shape[1:]).swapaxes(1, 2)
+            return pool.at[pages[:nb]].set(blocks.astype(pool.dtype))
 
         if stacked:
             return {"k_pages": jax.vmap(scatter)(pc["k_pages"], k[:, 0]),
@@ -299,9 +301,9 @@ def make_paged_attn_step(cfg: ModelConfig, block_tables: jax.Array,
         rows = jnp.arange(b)
         page_idx = block_tables[rows, pos // page_size]
         slot_idx = pos % page_size
-        kp = cache["k_pages"].at[page_idx, slot_idx].set(
+        kp = cache["k_pages"].at[page_idx, :, slot_idx].set(
             k.astype(cache["k_pages"].dtype))
-        vp = cache["v_pages"].at[page_idx, slot_idx].set(
+        vp = cache["v_pages"].at[page_idx, :, slot_idx].set(
             v.astype(cache["v_pages"].dtype))
 
         if fused:
@@ -361,9 +363,9 @@ def make_paged_span_step(cfg: ModelConfig, block_tables: jax.Array,
         blk = jnp.minimum(positions // page_size, nb - 1)
         page_idx = jnp.where(safe, block_tables[rows, blk], SCRATCH_PAGE)
         slot_idx = jnp.where(safe, positions % page_size, 0)
-        kp = cache["k_pages"].at[page_idx, slot_idx].set(
+        kp = cache["k_pages"].at[page_idx, :, slot_idx].set(
             k.astype(cache["k_pages"].dtype))
-        vp = cache["v_pages"].at[page_idx, slot_idx].set(
+        vp = cache["v_pages"].at[page_idx, :, slot_idx].set(
             v.astype(cache["v_pages"].dtype))
 
         out = ops.paged_attention(q, kp, vp, block_tables, pos + 1,

@@ -38,12 +38,10 @@ def default_cache_path() -> str:
 
 def device_kind() -> str:
     """Backend tag used in cache keys; interpret-mode results are tagged
-    ``cpu`` so they never masquerade as real-device timings."""
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    ``cpu`` so they never masquerade as real-device timings.  A backend
+    that fails to start raises here rather than passing for the CPU."""
+    import jax
+    return jax.default_backend()
 
 
 class ScheduleCache:

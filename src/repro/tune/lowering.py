@@ -383,21 +383,21 @@ def candidates(spec: OpSpec,
         # express the winner's bn in per-projection columns, snapped to
         # a lane-aligned divisor of Nkv (integer division by G+2 would
         # silently drop the MXU alignment every other GEMM candidate
-        # carries); the fused VMEM filter rejects what the joint
+        # carries, and Mosaic refuses a block that splits the lane dim
+        # unaligned); the fused VMEM filter rejects what the joint
         # residents overflow
         from repro.core.loopnest import divisors
         M, Nkv, K, G = spec.dims
         joint = matmul_tile_candidates(M, (G + 2) * Nkv, K,
                                        spec.itemsize, budget, target,
                                        top=top)
+        lane = min(target.lane, Nkv)
 
         def per_projection(bn_joint: int) -> int:
             cap = max(bn_joint // (G + 2), 1)
             aligned = [d for d in divisors(Nkv)
-                       if d <= cap and d % min(target.lane, Nkv) == 0]
-            if aligned:
-                return max(aligned)
-            return max(d for d in divisors(Nkv) if d <= cap)
+                       if d <= cap and d % lane == 0]
+            return max(aligned) if aligned else lane
 
         raw = []
         for bm, bk, bn in joint:

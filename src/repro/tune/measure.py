@@ -31,6 +31,7 @@ def make_inputs(schedule: Schedule, seed: int = 0):
     the host-side dilation, so it measures as one.
     """
     import jax.numpy as jnp
+    from repro.kernels.flash_decode import page_pool_shape
 
     spec = schedule.spec
     rng = np.random.default_rng(seed)
@@ -40,10 +41,9 @@ def make_inputs(schedule: Schedule, seed: int = 0):
         (page,) = schedule.tiles
         n_blocks = -(-S // page)
         q = jnp.asarray(rng.normal(size=(1, 1, G, D)), spec.dtype)
-        kp = jnp.asarray(rng.normal(size=(n_blocks, page, 1, D)),
-                         spec.dtype)
-        vp = jnp.asarray(rng.normal(size=(n_blocks, page, 1, D)),
-                         spec.dtype)
+        pool = page_pool_shape(n_blocks, 1, page, D)
+        kp = jnp.asarray(rng.normal(size=pool), spec.dtype)
+        vp = jnp.asarray(rng.normal(size=pool), spec.dtype)
         bt = jnp.asarray(rng.permutation(n_blocks)[None, :], jnp.int32)
         lengths = jnp.asarray([S], jnp.int32)
         wo = jnp.asarray(rng.normal(size=(1, G * D, E)) * 0.1,
@@ -75,10 +75,9 @@ def make_inputs(schedule: Schedule, seed: int = 0):
         page_dtype = (jnp.float8_e4m3fn if spec.op == "flash_decode_fp8"
                       else spec.dtype)
         q = jnp.asarray(rng.normal(size=(1, 1, G, D)), spec.dtype)
-        kp = jnp.asarray(rng.normal(size=(n_blocks, page, 1, D)),
-                         page_dtype)
-        vp = jnp.asarray(rng.normal(size=(n_blocks, page, 1, D)),
-                         page_dtype)
+        pool = page_pool_shape(n_blocks, 1, page, D)
+        kp = jnp.asarray(rng.normal(size=pool), page_dtype)
+        vp = jnp.asarray(rng.normal(size=pool), page_dtype)
         bt = jnp.asarray(rng.permutation(n_blocks)[None, :], jnp.int32)
         lengths = jnp.asarray([S], jnp.int32)
         if spec.op == "flash_decode_fp8":

@@ -22,7 +22,9 @@ def run_py(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    # the virtual devices are CPU devices: never let a child reach for
+    # an accelerator runtime
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=600)
@@ -33,6 +35,7 @@ def run_py(code: str, devices: int = 8) -> str:
 def test_shardmap_moe_matches_dense_reference():
     run_py("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced
         from repro.models import layers as L
         from repro.models.base import build
@@ -41,12 +44,12 @@ def test_shardmap_moe_matches_dense_reference():
         cfg = dataclasses.replace(get_reduced('qwen3-moe-235b-a22b'),
                                   dtype=jnp.float32, capacity_factor=8.0)
         params = build(L.moe_defs(cfg, 2), 'init', jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         set_axis_mapping({'data': ('data',), 'model': 'model'})
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
                               jnp.float32)
         ref_out, _ = L._moe_apply_ref(cfg, params, x)
-        with mesh:
+        with jax.set_mesh(mesh):
             out, aux = jax.jit(lambda p, x: L.moe_apply(cfg, p, x))(
                 params, x)
         err = float(jnp.max(jnp.abs(out - ref_out)))
@@ -60,6 +63,7 @@ def test_sharded_train_step_lowers_and_runs():
     runs one step and checks finite loss + sharded params."""
     run_py("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_reduced
         from repro.models import transformer as T
@@ -71,14 +75,14 @@ def test_sharded_train_step_lowers_and_runs():
         cfg = dataclasses.replace(
             get_reduced('granite-3-8b'), d_model=64, n_heads=4,
             n_kv_heads=2, d_ff=128)
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh = make_mesh((2, 2), ('data', 'model'))
         mapping = {'data': ('data',), 'model': 'model'}
         set_axis_mapping(mapping)
         specs = translate_tree(T.param_specs(cfg, 2), mapping)
         shardings = jax.tree.map(
             lambda s: NamedSharding(mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P))
-        with mesh:
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda k: T.init_params(cfg, k, 2),
                 out_shardings=shardings)(jax.random.PRNGKey(0))
@@ -91,18 +95,41 @@ def test_sharded_train_step_lowers_and_runs():
     """)
 
 
+def test_chip_smoke_train_phase_matches_one_device():
+    """``chip_smoke.py --four-chips``'s phase on 4 virtual devices at a
+    small width: sharded losses and updates equal one device's, params
+    sharded."""
+    out = run_py(f"""
+        import dataclasses, importlib.util, jax
+        from repro.configs import get_reduced
+        spec = importlib.util.spec_from_file_location(
+            'chip_smoke', {os.path.join(SRC, '..', 'chip_smoke.py')!r})
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        cfg = dataclasses.replace(get_reduced('granite-3-8b'), n_layers=2)
+        res = smoke.train_phase(cfg, jax.devices(), seq_len=32, batch=4)
+        assert res['finite'], res
+        assert res['max_rel_diff'] <= smoke.LOSS_RTOL, res
+        assert res['update_gap'] <= smoke.PARAM_RTOL, res
+        assert res['bytes_on_device0'] <= 0.3 * res['param_bytes'], res
+        print('OK', res['max_rel_diff'])
+    """, devices=4)
+    assert out.startswith("OK")
+
+
 def test_dryrun_single_cell_small_mesh():
     """The dry-run machinery end-to-end on an 8-device (4,2) mesh with a
     reduced config (fast): lower + compile + artifact fields."""
     run_py("""
         import dataclasses, jax
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced, SHAPES, ARCHS
         from repro.launch import shapes as S
         from repro.models.sharding import set_axis_mapping
         import repro.launch.dryrun as dr
 
         cfg = get_reduced('gemma2-9b')
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         shape = dataclasses.replace(SHAPES['train_4k'], seq_len=64,
                                     global_batch=8)
         mapping = S.axis_mapping(cfg, shape, mesh)
@@ -111,7 +138,7 @@ def test_dryrun_single_cell_small_mesh():
         C.SHAPES['tiny_train'] = dataclasses.replace(
             shape, name='tiny_train')
         low = S.input_specs(cfg, 'tiny_train', mesh, model_ax=2)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(low.fn, in_shardings=low.in_shardings,
                                out_shardings=low.out_shardings
                                ).lower(*low.args_shapes).compile()
@@ -126,6 +153,7 @@ def test_fsdp_mapping_removes_tp_collectives():
     tp_fsdp on the same tiny dense cell (the §Perf it.1 claim, in CI)."""
     out = run_py("""
         import dataclasses, jax
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced, SHAPES
         from repro.launch import shapes as S
         from repro.models.sharding import set_axis_mapping
@@ -133,7 +161,7 @@ def test_fsdp_mapping_removes_tp_collectives():
         import repro.launch.dryrun as dr
 
         cfg = get_reduced('granite-3-8b')
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         C.SHAPES['tiny_train'] = dataclasses.replace(
             SHAPES['train_4k'], name='tiny_train', seq_len=64,
             global_batch=8)
@@ -142,7 +170,7 @@ def test_fsdp_mapping_removes_tp_collectives():
             shape = C.SHAPES['tiny_train']
             set_axis_mapping(S.axis_mapping(cfg, shape, mesh, par))
             low = S.input_specs(cfg, 'tiny_train', mesh, parallelism=par)
-            with mesh:
+            with jax.set_mesh(mesh):
                 comp = jax.jit(low.fn, in_shardings=low.in_shardings,
                                out_shardings=low.out_shardings
                                ).lower(*low.args_shapes).compile()

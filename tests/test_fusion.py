@@ -12,6 +12,7 @@ from repro.core.fusion import (Epilogue, FusedProblem, fused_energy_pj,
                                fused_multicore_dram_bytes, optimize_fused)
 from repro.core.loopnest import Problem
 from repro.kernels import ops
+from repro.kernels.flash_decode import page_pool_shape
 
 BUDGET = 2 * 1024 * 1024
 
@@ -333,10 +334,9 @@ def test_flash_decode_oproj_matches_unfused_pair(window, logit_cap):
     B, hkv, G, D, page, nb, E = 3, 2, 3, 16, 8, 4, 40
     n_pages = B * nb + 1
     q = jnp.asarray(rng.normal(size=(B, hkv * G, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)),
-                     jnp.float32)
+    pool = page_pool_shape(n_pages, hkv, page, D)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
     bt = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb),
                      jnp.int32)
     lengths = jnp.asarray([1, 13, 32], jnp.int32)

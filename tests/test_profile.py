@@ -16,6 +16,7 @@ import pytest
 
 from repro import tune
 from repro.configs import get_reduced
+from repro.core import TPU_V5E
 from repro.core.energy import DRAM_PJ_PER_16B
 from repro.obs import (DramLedger, KernelProfiler, MetricsRegistry, Obs,
                        StepTracer, kernel_hbm_bytes, read_miss_log)
@@ -109,7 +110,13 @@ def test_profiler_rooflines_observed_resolutions():
     assert row["source"] == "analytic"
     assert row["fidelity_ratio"] == pytest.approx(1.0)
     assert rep["fidelity_misses"] == []
-    assert row["time_us"] > 0 and row["bound"] in ("memory", "compute")
+    assert row["time_us"] > 0 and row["achieved_gflops"] >= 0
+    # on a CPU there is no chip to price against: no share, no target
+    assert rep["target"] is None
+    assert "peak_frac" not in row and "bound" not in row
+    prof.target = TPU_V5E               # as on a v5e
+    row = prof.roofline_report()["per_op"][key]
+    assert row["bound"] in ("memory", "compute")
     assert 0 <= row["peak_frac"] <= 1.0   # host-only scope: ~0 of peak
     t = rep["totals"]
     assert t["dispatches"] == 2 and t["hbm_bytes"] == row["hbm_bytes"]

@@ -141,15 +141,15 @@ def test_flash_decode_fp8_kernel_matches_oracle(window, logit_cap):
     """fp8-page Pallas kernel (interpret) == fp32-dequant dense oracle
     over ragged lengths, shuffled block tables and per-head scales."""
     from repro.kernels.flash_decode import (flash_decode_fp8,
+                                            page_pool_shape,
                                             paged_attention_fp8_ref)
     rng = np.random.default_rng(6)
     B, hkv, G, D, page, nb = 3, 2, 3, 16, 8, 4
     n_pages = B * nb + 1
     q = jnp.asarray(rng.normal(size=(B, hkv, G, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)),
-                     jnp.float8_e4m3fn)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)),
-                     jnp.float8_e4m3fn)
+    pool = page_pool_shape(n_pages, hkv, page, D)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float8_e4m3fn)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float8_e4m3fn)
     ks = jnp.asarray(rng.uniform(0.5, 2.0, size=(hkv,)), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.5, 2.0, size=(hkv,)), jnp.float32)
     bt = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb), jnp.int32)
@@ -166,14 +166,14 @@ def test_paged_attention_routes_fp8_pools():
     """ops.paged_attention on a 1-byte pool: unit-scale kernel output ==
     the plain oracle on cast pages (the dense-path fp8 semantics)."""
     from repro.kernels import ops
-    from repro.kernels.flash_decode import paged_attention_ref
+    from repro.kernels.flash_decode import (page_pool_shape,
+                                            paged_attention_ref)
     rng = np.random.default_rng(7)
     B, hkv, G, D, page, nb = 2, 2, 2, 8, 4, 3
     q = jnp.asarray(rng.normal(size=(B, hkv * G, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(B * nb + 1, page, hkv, D)),
-                     jnp.float8_e4m3fn)
-    vp = jnp.asarray(rng.normal(size=(B * nb + 1, page, hkv, D)),
-                     jnp.float8_e4m3fn)
+    pool = page_pool_shape(B * nb + 1, hkv, page, D)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float8_e4m3fn)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float8_e4m3fn)
     bt = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb), jnp.int32)
     lengths = jnp.asarray([5, 11], jnp.int32)
     out = ops.paged_attention(q, kp, vp, bt, lengths, use_kernel=True,
@@ -183,7 +183,7 @@ def test_paged_attention_routes_fp8_pools():
                                np.asarray(ref.reshape(B, hkv * G, D)),
                                rtol=1e-5, atol=1e-5)
     with pytest.raises(ValueError):
-        wide = jnp.zeros((B * nb + 1, page, hkv, D), jnp.float32)
+        wide = jnp.zeros(pool, jnp.float32)
         ops.paged_attention(q, wide, wide, bt, lengths,
                             k_scale=jnp.ones(hkv))
 

@@ -28,13 +28,15 @@ def _cfg(arch: str):
 def test_flash_decode_kernel_matches_oracle(window, logit_cap):
     """Pallas kernel (interpret) == dense oracle over ragged cache
     lengths, shuffled block tables, GQA groups, partial last pages."""
-    from repro.kernels.flash_decode import flash_decode, paged_attention_ref
+    from repro.kernels.flash_decode import (flash_decode, page_pool_shape,
+                                            paged_attention_ref)
     rng = np.random.default_rng(0)
     B, hkv, G, D, page, nb = 3, 2, 3, 16, 8, 4
     n_pages = B * nb + 1
     q = jnp.asarray(rng.normal(size=(B, hkv, G, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, D)), jnp.float32)
+    pool = page_pool_shape(n_pages, hkv, page, D)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
     bt = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb), jnp.int32)
     lengths = jnp.asarray([1, 13, 32], jnp.int32)   # ragged, incl. edges
     out_k = flash_decode(q, kp, vp, bt, lengths, window=window,
@@ -73,16 +75,18 @@ def test_paged_attention_matches_dense_attention_decode():
 
     # paged: same content scattered to (shuffled) pages per request
     n_pages = B * nb + 1
-    kp = jnp.zeros((n_pages, page, hkv, hd), jnp.float32)
-    vp = jnp.zeros((n_pages, page, hkv, hd), jnp.float32)
+    kp = jnp.zeros((n_pages, hkv, page, hd), jnp.float32)
+    vp = jnp.zeros((n_pages, hkv, page, hd), jnp.float32)
     bt = np.zeros((B, nb), np.int32)
     perm = 1 + rng.permutation(B * nb)
     for b in range(B):
         for i in range(nb):
             pg = int(perm[b * nb + i])
             bt[b, i] = pg
-            kp = kp.at[pg].set(k_dense[b, i * page:(i + 1) * page])
-            vp = vp.at[pg].set(v_dense[b, i * page:(i + 1) * page])
+            kp = kp.at[pg].set(
+                k_dense[b, i * page:(i + 1) * page].swapaxes(0, 1))
+            vp = vp.at[pg].set(
+                v_dense[b, i * page:(i + 1) * page].swapaxes(0, 1))
     lengths = jnp.full((B,), pos + 1, jnp.int32)
     out = ops.paged_attention(q, kp, vp, jnp.asarray(bt), lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
