@@ -32,8 +32,9 @@ the modeled-vs-measured DRAM report) under a ``metrics`` field;
 ``check_bench.py`` ignores fields it doesn't guard, so snapshot schema
 growth never forces an ``--update``.  ``--trace`` / ``--metrics-out`` /
 ``--miss-log`` wire a full :class:`repro.obs.Obs` into the measured
-paged engine (tracing inserts device fences — don't trust traced
-throughput numbers; see docs/observability.md).
+paged engine; ``--trace DIR`` records the run in a JAX profiler session
+(a Perfetto file with host spans and device lanes on one clock; the
+spans never wait for the device, see docs/observability.md).
 
     PYTHONPATH=src python -m benchmarks.serve_bench --smoke
 """
@@ -217,10 +218,11 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write every record as machine-readable "
                          "JSON (the BENCH_serve.json trajectory file)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="Chrome-trace span timeline for the measured "
-                         "paged engine; inserts device fences, so "
-                         "traced throughput numbers are NOT comparable")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="record the run in a JAX profiler session under "
+                         "DIR (a Perfetto file: engine spans and device "
+                         "lanes on one clock); the session itself costs "
+                         "some host time")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the measured paged engine's metrics "
                          "snapshot (registry + DRAM report) as JSON")

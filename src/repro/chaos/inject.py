@@ -12,9 +12,8 @@ runs exercise the *production* failure paths rather than test doubles:
   drops plan entries; the engine's plan validation must make duplicate
   entries idempotent and dropped entries merely late, never wrong.
 * :class:`CorruptScheduleCache` — a schedule cache whose hits are
-  deliberately pessimal tiles (moved here from ``repro.profile``, which
-  re-exports it): still runnable, but strictly worse, exercising the
-  profiler's model-fidelity gate.
+  deliberately pessimal tiles: still runnable, but strictly worse, so a
+  caller can see what a stale or corrupted schedule cache serves.
 
 NaN/Inf logit poisoning needs device cooperation and therefore lives on
 the engine itself (``PagedEngine.inject_logit_fault``, guarded by
@@ -136,9 +135,7 @@ class CorruptScheduleCache:
     For ops matching ``match`` it returns the analytic winner with every
     halvable tile halved — still dividing, still runnable, but moving
     strictly more HBM bytes (smaller blocks mean more refetch under the
-    grid's DMA elision).  Installed via ``tune.set_default_cache`` by
-    ``repro.profile --corrupt`` to exercise the profiler's fidelity
-    gate end to end.
+    grid's DMA elision).  Install it with ``tune.set_default_cache``.
     """
 
     def __init__(self, match: str):

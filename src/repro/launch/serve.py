@@ -101,11 +101,11 @@ def main() -> None:
                     help="write the metrics snapshot (registry + "
                          "modeled-vs-measured DRAM report) as JSON "
                          "(docs/observability.md)")
-    ap.add_argument("--trace", metavar="PATH", default=None,
-                    help="write a Chrome-trace (chrome://tracing / "
-                         "Perfetto) span timeline of every engine step; "
-                         "inserts block_until_ready fences, so traced "
-                         "runs are NOT for throughput numbers")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="record the run in a JAX profiler session under "
+                         "DIR: one Perfetto file with the engine's spans "
+                         "and the device lanes on one clock (the spans "
+                         "never wait for the device)")
     ap.add_argument("--miss-log", metavar="PATH", default=None,
                     help="append schedule-cache misses as JSONL tuning "
                          "targets for python -m repro.tune "
@@ -141,12 +141,12 @@ def main() -> None:
             if lines:
                 print("modeled-vs-measured DRAM bytes per op key:")
                 print(lines)
-        if args.trace:
-            print(f"chrome trace -> {args.trace}")
         if args.miss_log:
             print(f"schedule-cache miss log -> {args.miss_log} "
                   "(replay: python -m repro.tune --from-telemetry)")
         engine.obs.close()
+        if engine.obs.session is not None:
+            print(f"perfetto trace -> {engine.obs.session.perfetto_path}")
 
     if args.engine == "paged":
         engine = PagedEngine(cfg, params, PagedServeConfig(
@@ -197,8 +197,11 @@ def main() -> None:
         if args.deadline or args.preempt or args.nan_guard \
                 or args.degrade:
             sections["lifecycle"] = engine.lifecycle_stats()
-        if sections:
-            print(format_metrics(sections))
+        snap = engine.obs.registry.snapshot()
+        sections["sched"] = {"queue_wait_us": snap["sched"]["queue_wait_us"]}
+        sections["engine"] = {k: snap["engine"][k]
+                              for k in ("compiles", "compile_s")}
+        print(format_metrics(sections))
         statuses = sorted({r.status.value for r in reqs})
         print(f"generated {emitted} tokens over {n_req} requests in "
               f"{dt:.2f}s ({tps:.1f} tok/s), statuses: "

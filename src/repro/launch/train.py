@@ -66,10 +66,10 @@ def main() -> None:
                     help="write the metrics snapshot (train gauges + "
                          "modeled-vs-measured DRAM report) as JSON — the "
                          "same flag serving has (docs/observability.md)")
-    ap.add_argument("--trace", metavar="PATH", default=None,
-                    help="write a Chrome-trace span timeline of every "
-                         "train step (step/grad/checkpoint spans + "
-                         "loss/throughput counter tracks)")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="record the run in a JAX profiler session under "
+                         "DIR: one Perfetto file with the train.step / "
+                         "train.checkpoint spans and the device lanes")
     ap.add_argument("--miss-log", metavar="PATH", default=None,
                     help="append schedule-cache misses as JSONL tuning "
                          "targets (meaningful with --blocked-kernels)")
@@ -107,12 +107,12 @@ def main() -> None:
         print(f"metrics snapshot -> {args.metrics_out}")
         snap = obs.snapshot()
         print(format_metrics({"train": snap.get("train", {})}))
-    if args.trace:
-        print(f"chrome trace -> {args.trace}")
     if args.miss_log:
         print(f"schedule-cache miss log -> {args.miss_log} "
               "(replay: python -m repro.tune --from-telemetry)")
     obs.close()
+    if obs.session is not None:
+        print(f"perfetto trace -> {obs.session.perfetto_path}")
 
 
 if __name__ == "__main__":

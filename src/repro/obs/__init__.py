@@ -1,19 +1,22 @@
-"""Serving observability: metrics registry, step-span tracing, and
-modeled-vs-measured DRAM accounting (docs/observability.md).
+"""Serving observability: metrics registry, host spans on the JAX
+profiler's clock, and modeled-vs-measured DRAM accounting
+(docs/observability.md).
 
 :class:`Obs` is the bundle the engines take (``PagedEngine(obs=...)``,
 ``DecodeEngine(obs=...)``): a :class:`~repro.obs.metrics.MetricsRegistry`
-that the engine, scheduler and kv-cache report into; an optional
-:class:`~repro.obs.trace.StepTracer` emitting Chrome-trace spans (the
-engines fence with ``block_until_ready`` ONLY when a tracer is
-attached); and a :class:`~repro.obs.dram.DramLedger` comparing the
-analytical model's predicted DRAM bytes against what the schedule
+that the engine, scheduler and kv-cache report into; with ``trace=DIR``
+a :class:`~repro.obs.trace.ProfilerSession` that records the engines'
+spans and the device lanes into one Perfetto file, stopped by
+:meth:`Obs.close`; and a :class:`~repro.obs.dram.DramLedger` comparing
+the analytical model's predicted DRAM bytes against what the schedule
 cache actually resolved, per op key, while logging schedule-cache
 misses for ``python -m repro.tune --from-telemetry``.
 
-An engine constructed without an ``obs`` argument builds a private
-``Obs()`` — registry and ledger always on (they are host-side integer
-arithmetic), tracer off.
+The engines emit their spans (:func:`~repro.obs.trace.span`) whether or
+not a session is open, and never wait for the device to do so.  An
+engine constructed without an ``obs`` argument builds a private
+``Obs()`` — registry and ledger always on (host-side integer
+arithmetic), no profiler session.
 """
 
 from __future__ import annotations
@@ -24,33 +27,31 @@ import os
 from repro.obs.dram import DramLedger, read_miss_log
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                format_metrics, hist_quantile)
-from repro.obs.profile import KernelProfiler, kernel_hbm_bytes
-from repro.obs.trace import NULL_SPAN, StepTracer, null_span
+from repro.obs.profile import kernel_hbm_bytes
+from repro.obs.trace import CompileWatch, ProfilerSession, instant, span
 
 __all__ = [
-    "Counter", "DramLedger", "Gauge", "Histogram", "KernelProfiler",
-    "MetricsRegistry", "NULL_SPAN", "Obs", "StepTracer", "format_metrics",
-    "hist_quantile", "kernel_hbm_bytes", "null_span", "read_miss_log",
+    "CompileWatch", "Counter", "DramLedger", "Gauge", "Histogram",
+    "MetricsRegistry", "Obs", "ProfilerSession", "format_metrics",
+    "hist_quantile", "instant", "kernel_hbm_bytes", "read_miss_log", "span",
 ]
 
 
 class Obs:
     """One observability bundle per engine (or shared across engines).
 
-    ``trace`` / ``miss_log`` accept paths for convenience; pass a
-    constructed :class:`StepTracer` / :class:`DramLedger` /
-    :class:`MetricsRegistry` to share instances across engines.
+    ``trace`` is a directory: a JAX profiler session starts there now
+    and stops at :meth:`close`.  Pass a constructed
+    :class:`DramLedger` / :class:`MetricsRegistry` to share instances
+    across engines.
     """
 
     def __init__(self, registry: MetricsRegistry | None = None,
-                 trace: StepTracer | str | os.PathLike | None = None,
+                 trace: str | os.PathLike | None = None,
                  dram: DramLedger | None = None,
                  miss_log: str | None = None):
         self.registry = registry if registry is not None else MetricsRegistry()
-        if trace is None or isinstance(trace, StepTracer):
-            self.tracer = trace
-        else:
-            self.tracer = StepTracer(trace)
+        self.session = ProfilerSession(trace) if trace is not None else None
         self.dram = dram if dram is not None else DramLedger(
             registry=self.registry, miss_log=miss_log)
 
@@ -70,8 +71,8 @@ class Obs:
             f.write("\n")
 
     def close(self) -> None:
-        if self.tracer is not None:
-            self.tracer.close()
+        if self.session is not None:
+            self.session.close()
         self.dram.close()
 
     def __enter__(self):

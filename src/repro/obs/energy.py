@@ -1,14 +1,13 @@
-"""Per-op energy pricing for the kernel profiler (docs/observability.md).
+"""Per-op energy pricing of a kernel dispatch (docs/observability.md).
 
 The paper's energy model (``core.energy`` / ``core.hierarchy``) prices a
 blocking string: every on-chip buffer at its size-dependent SRAM access
 cost, the DRAM boundary at the fixed per-16-byte cost, plus the MAC
-array.  The profiler needs that split for the schedules the kernels
-*actually ran* — with one correction: the DRAM component is re-priced on
-the kernel's measured HBM bytes (the grid's exact block transfers,
-``kernels.*.hbm_bytes``) rather than the model's idealized stream, so
-observed fidelity misses (a stale cached schedule moving more bytes than
-the analytic winner would) show up in picojoules too.
+array.  :func:`op_energy_pj` gives that split for the schedule a kernel
+runs, with one correction: the DRAM component is priced on the kernel's
+exact HBM bytes (the grid's block transfers, ``kernels.*.hbm_bytes``)
+rather than the model's idealized stream, so a schedule moving more
+bytes than the analytic winner shows up in picojoules too.
 
 Everything returns plain JSON-safe dicts; ops whose resolved tiles the
 kernels cannot run directly (non-dividing — the oracle-fallback path)

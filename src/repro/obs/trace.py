@@ -1,135 +1,108 @@
-"""Chrome-trace-format step-span tracer for the serving engines.
+"""Host spans on the JAX profiler's clock, and the compilations an
+engine step triggers.
 
-Emits the Trace Event Format that ``chrome://tracing`` and Perfetto
-load: a JSON array of complete-duration events (``"ph": "X"``) with
-microsecond timestamps, written one event per line so the file doubles
-as line-oriented JSONL while staying a single valid JSON document
-(the array is opened on construction and closed by :meth:`close`).
+:func:`span` returns a ``jax.profiler.TraceAnnotation``: with no
+profiler session active it costs about a microsecond and records
+nothing; inside a session (``jax.profiler.start_trace``, or
+:class:`ProfilerSession`) it lands on the calling thread's line of the
+profiler's own trace, on the device's clock, with its keyword arguments
+as event stats.  Arguments are passed on only while a session is
+active.  Spans never wait for the device: a span around a dispatch
+times the host's part, and the device lanes of the same trace show the
+device's.
 
-JAX-awareness is the engines' side of the contract: device dispatches
-return before the work finishes, so a span around a ``jit`` call times
-only host-side dispatch unless the engine fences with
-``jax.block_until_ready`` — which it does ONLY when a tracer is
-attached.  With tracing off the engines never construct span objects,
-never fence, and pay nothing (see ``tests/test_obs.py``'s zero-sync
-guard).
+:class:`CompileWatch` counts the backend compilations that fire while
+it is open (one process-wide ``jax.monitoring`` listener, registered on
+first use), so an engine can say which of its steps compiled.
+
+The span names the engines emit are a fixed contract, listed in
+docs/observability.md.
 """
 
 from __future__ import annotations
 
-import json
+import glob
 import os
-import time
+
+import jax
+from jax._src.lib import _profiler
+
+_session_active = _profiler.TraceMe.is_enabled
+_Annotation = jax.profiler.TraceAnnotation
+
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
-class _Span:
-    """Context manager emitting one complete ('X') event on exit.
-
-    Reused per-call (not pooled): creation is two attribute stores.
-    """
-
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
-
-    def __init__(self, tracer: "StepTracer", name: str, cat: str, args):
-        self.tracer, self.name, self.cat, self.args = tracer, name, cat, args
-
-    def __enter__(self):
-        self.t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        tr = self.tracer
-        ev = {"name": self.name, "ph": "X", "cat": self.cat,
-              "ts": (self.t0 - tr.epoch_ns) / 1000.0,
-              "dur": (t1 - self.t0) / 1000.0,
-              "pid": tr.pid, "tid": 0}
-        if self.args:
-            ev["args"] = self.args
-        tr._emit(ev)
-        return False
+def span(name: str, **args) -> _Annotation:
+    """``with span("engine.prefill", rid=3): ...`` — one host span."""
+    if args and _session_active():
+        return _Annotation(name, **args)
+    return _Annotation(name)
 
 
-class _NullSpan:
-    """Shared no-op span for the tracing-off path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+def instant(name: str, **args) -> None:
+    """A zero-length span: a marker at this point of the host thread."""
+    with span(name, **args):
+        pass
 
 
-NULL_SPAN = _NullSpan()
+class ProfilerSession:
+    """One JAX profiler session writing under ``log_dir``: the
+    ``.xplane.pb`` and a Perfetto ``perfetto_trace.json.gz`` that shows
+    the host spans and the device lanes on one clock.  Host Python
+    calls are not traced (only annotations and runtime events)."""
 
-
-def null_span(name: str, cat: str = "serve", args=None) -> _NullSpan:
-    return NULL_SPAN
-
-
-class StepTracer:
-    """Writes Chrome-trace events to ``path``.
-
-    Nested :meth:`span` calls produce properly-nested intervals (inner
-    spans close — and therefore appear in the file — before their
-    enclosing span; viewers nest by interval containment, not file
-    order).  Timestamps are microseconds from a per-tracer epoch on a
-    monotonic clock.
-    """
-
-    def __init__(self, path: str | os.PathLike):
-        self.path = os.fspath(path)
-        d = os.path.dirname(self.path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        self._f = open(self.path, "w")
-        self._f.write("[\n")
-        self._first = True
-        self.pid = os.getpid()
-        self.epoch_ns = time.perf_counter_ns()
-
-    def _emit(self, ev: dict) -> None:
-        if self._f is None:
-            return
-        if self._first:
-            self._first = False
-        else:
-            self._f.write(",\n")
-        self._f.write(json.dumps(ev, separators=(",", ":")))
-
-    def span(self, name: str, cat: str = "serve", args=None) -> _Span:
-        """``with tracer.span("plan"): ...`` — one 'X' event on exit."""
-        return _Span(self, name, cat, args)
-
-    def instant(self, name: str, cat: str = "serve", args=None) -> None:
-        """Zero-duration marker ('i' event, thread scope)."""
-        ev = {"name": name, "ph": "i", "s": "t", "cat": cat,
-              "ts": (time.perf_counter_ns() - self.epoch_ns) / 1000.0,
-              "pid": self.pid, "tid": 0}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
-
-    def counter(self, name: str, values: dict) -> None:
-        """Counter-track sample ('C' event): ``values`` maps series name
-        to number; Perfetto renders one stacked track per name."""
-        self._emit({"name": name, "ph": "C",
-                    "ts": (time.perf_counter_ns() - self.epoch_ns) / 1000.0,
-                    "pid": self.pid, "tid": 0, "args": dict(values)})
+    def __init__(self, log_dir: str | os.PathLike):
+        self.log_dir = os.fspath(log_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.log_dir, create_perfetto_trace=True,
+                                 profiler_options=opts)
+        self._open = True
 
     def close(self) -> None:
-        """Close the JSON array and the file.  Idempotent."""
-        if self._f is None:
-            return
-        self._f.write("\n]\n")
-        self._f.close()
-        self._f = None
+        """Stop the session.  Idempotent."""
+        if self._open:
+            self._open = False
+            jax.profiler.stop_trace()
+
+    @property
+    def perfetto_path(self) -> str | None:
+        """The newest Perfetto file the session wrote (after close)."""
+        found = sorted(glob.glob(os.path.join(
+            self.log_dir, "**", "perfetto_trace.json.gz"), recursive=True))
+        return found[-1] if found else None
+
+
+_watches: list = []          # the CompileWatches open, innermost last
+_listening = False
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event == BACKEND_COMPILE and _watches:
+        w = _watches[-1]
+        w.compiles.inc()
+        w.compile_s.inc(secs)
+
+
+class CompileWatch:
+    """Counts backend compilations fired while open into ``registry`` as
+    ``engine.compiles`` and ``engine.compile_s``.  Reentrant across
+    engines: the innermost open watch takes the count."""
+
+    def __init__(self, registry):
+        global _listening
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+        self.compiles = registry.counter("engine.compiles")
+        self.compile_s = registry.counter("engine.compile_s")
 
     def __enter__(self):
+        _watches.append(self)
         return self
 
     def __exit__(self, *exc):
-        self.close()
+        _watches.pop()
         return False

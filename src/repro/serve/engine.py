@@ -65,7 +65,7 @@ from repro.kernels import ops
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.obs import Obs
-from repro.obs.trace import null_span
+from repro.obs.trace import CompileWatch, instant, span
 from repro.serve import kv_cache as KV
 from repro.serve.lifecycle import DegradationController, RequestStatus
 from repro.serve.scheduler import Request, Scheduler
@@ -124,25 +124,19 @@ class DecodeEngine:
             extras["enc_embeds"] = enc_embeds
         if prefix_embeds is not None:
             extras["prefix_embeds"] = prefix_embeds
-        tr = self.obs.tracer
-        sp = tr.span if tr is not None else null_span
-        with sp("prefill", cat="static"), \
+        with span("static.prefill"), \
                 self.obs.dram.scope(f"static_prefill[{s0}]"):
             logits, cache = self._prefill(self.params, jnp.asarray(prompts),
                                           max_seq=sc.max_seq, **extras)
-            if tr is not None:
-                jax.block_until_ready(logits)
         pos = s0 + (cfg.prefix_tokens if prefix_embeds is not None else 0)
         rng = jax.random.PRNGKey(sc.seed)
         # the whole token loop runs on device (lax.scan, sampling
         # included) and transfers once — no per-token host sync
-        with sp("decode", cat="static"), \
+        with span("static.decode"), \
                 self.obs.dram.scope(f"static_generate[{n_tokens}]"):
             out = self._gen(self.params, logits, cache, jnp.int32(pos), rng,
                             n_tokens=n_tokens)
-            if tr is not None:
-                jax.block_until_ready(out)
-        with sp("readback", cat="static"):
+        with span("static.readback"):
             host = np.asarray(out)
         self._m_prefill_tokens.inc(b * s0)
         self._m_decode_tokens.inc(b * n_tokens)
@@ -316,14 +310,15 @@ class PagedEngine:
         self.prefix_cache = (KV.PrefixCache(allocator, self.page_size,
                                             metrics=reg)
                              if self.prefix_caching else None)
+        self._clock = time.monotonic_ns    # injectable for deterministic tests
         self.scheduler = Scheduler(sc.max_batch, self.page_size,
                                    allocator, sc.max_seq,
                                    age_limit=sc.age_limit,
                                    prefix_cache=self.prefix_cache,
                                    metrics=reg,
-                                   max_retries=sc.max_retries)
-        self.degrade = (DegradationController(reg, tracer=self.obs.tracer)
-                        if sc.degrade else None)
+                                   max_retries=sc.max_retries,
+                                   clock=lambda: self._clock())
+        self.degrade = DegradationController(reg) if sc.degrade else None
 
         b = sc.max_batch
         self._block_tables = jnp.zeros((b, self.max_blocks), jnp.int32)
@@ -338,7 +333,6 @@ class PagedEngine:
         # reads it; the host mirror skips no-op device updates)
         self._poison = jnp.zeros(b, jnp.float32)
         self._poison_host = np.zeros(b, np.float64)
-        self._clock = time.monotonic_ns    # injectable for deterministic tests
         self._sched_steps = 0              # TTL / expiry step counter
         self._joins: dict[int, Any] = {}           # bucket -> jitted join
         self._chunk_fns: dict[int, Any] = {}       # span width -> chunk fn
@@ -365,6 +359,7 @@ class PagedEngine:
         self._m_status = {s: reg.counter(f"lifecycle.{s.value}")
                           for s in RequestStatus}
         self._m_nan_trips = reg.counter("lifecycle.nan_guard_trips")
+        self._compile_watch = CompileWatch(reg)
 
     # -- request API ----------------------------------------------------------
 
@@ -404,7 +399,7 @@ class PagedEngine:
         byte-exact output and status PREEMPTED_RETRIED."""
         for slot, r in self.scheduler.running.items():
             if r.rid == rid:
-                self._preempt_slot(slot, self.obs.tracer)
+                self._preempt_slot(slot)
                 return True
         return False
 
@@ -489,33 +484,34 @@ class PagedEngine:
         """One continuous-batching iteration; returns finished requests
         (with ``.output`` filled).
 
-        With a tracer attached, the step and its phases (host prep,
-        ``plan_step``, device dispatches, readback) emit Chrome-trace
-        spans, and each device dispatch is fenced with
-        ``block_until_ready`` so span durations mean device time.  With
-        no tracer, ``sp`` is the shared no-op span and NO fence runs —
-        the hot path stays async (guarded by ``tests/test_obs.py``).
+        The step and its phases emit host spans (``engine.*``,
+        ``sched.plan``; docs/observability.md), recorded only while a
+        profiler session is open.  Nothing here waits for the device
+        except the readbacks that need its results (``engine.readback``):
+        dispatches stay asynchronous with or without a session (guarded
+        by ``tests/test_obs.py``).  ``engine.step_us`` is the host time
+        of this call, not device time.
         """
-        t0 = time.perf_counter_ns()
-        tr = self.obs.tracer
-        sp = tr.span if tr is not None else null_span
-        self.last_step_tokens = 0
-        step_rids: set[int] = set()
-        with sp("step", cat="engine", args={"step": self._step_count}):
-            finished = self._step_inner(sp, tr, step_rids)
-        self._m_steps.inc()
-        self._m_step_us.observe((time.perf_counter_ns() - t0) / 1000.0)
-        self.obs.dram.end_step(sorted(step_rids))
+        self._sched_steps += 1
+        with span("engine.step", step=self._sched_steps), \
+                self._compile_watch:
+            t0 = time.perf_counter_ns()
+            self.last_step_tokens = 0
+            step_rids: set[int] = set()
+            finished = self._step_inner(step_rids)
+            self._m_steps.inc()
+            self._m_step_us.observe((time.perf_counter_ns() - t0) / 1000.0)
+            self.obs.dram.end_step(sorted(step_rids))
         return finished
 
-    def _step_inner(self, sp, tr, step_rids: set[int]) -> list[Request]:
-        self._sched_steps += 1
+    def _step_inner(self, step_rids: set[int]) -> list[Request]:
         now = self._clock()
         finished: list[Request] = []
         # lifecycle sweep: queued deadline/TTL expiry and cancellation
         # drain before admission so a dead request never takes pages
-        for req in self.scheduler.expire(now, self._sched_steps):
-            self._finish(req, finished)
+        with span("engine.expire"):
+            for req in self.scheduler.expire(now, self._sched_steps):
+                self._finish(req, finished)
         # degradation ladder: one control tick per step, applied to THIS
         # step's spec/chunk/preemption decisions
         decode_chunk = self.sc.decode_chunk
@@ -533,11 +529,10 @@ class PagedEngine:
         if allow_preempt:
             victim = self.scheduler.preempt_candidate(force=force_preempt)
             if victim is not None:
-                with sp("preempt", cat="sched"):
-                    self._preempt_slot(victim, tr)
-        with sp("host_prep", cat="engine"):
-            for req in self.scheduler.admit():
-                step_rids.add(req.rid)
+                self._preempt_slot(victim)
+        for req in self.scheduler.admit():
+            step_rids.add(req.rid)
+            with span("engine.admit", rid=req.rid):
                 row = np.full(self.max_blocks, KV.SCRATCH_PAGE, np.int32)
                 row[:len(req.pages)] = req.pages
                 self._block_tables = self._block_tables.at[req.slot].set(
@@ -552,7 +547,7 @@ class PagedEngine:
                     self._m_prefix_saved.inc(req.prefilled)
                     if req.cow_fork is not None:
                         src, dst = req.cow_fork
-                        with sp("dispatch.fork", cat="device"), \
+                        with span("engine.fork", rid=req.rid), \
                                 self.obs.dram.scope("cow_fork"):
                             self.cache = self._get_fork_fn()(
                                 self.cache, jnp.int32(src), jnp.int32(dst))
@@ -568,10 +563,7 @@ class PagedEngine:
                     # decode-ready this very step instead of waiting a
                     # scheduling round (longer tails go through plan_step)
                     if req.prompt_len - req.prefilled <= self.prefill_chunk:
-                        with sp("dispatch.prefill", cat="device"):
-                            self._prefill_one_chunk(req)
-                            if tr is not None:
-                                jax.block_until_ready(self._cur_tok)
+                        self._prefill_one_chunk(req)
                     continue
                 if (not self.prefill_chunk
                         or req.prompt_len <= self.prefill_chunk):
@@ -580,17 +572,14 @@ class PagedEngine:
                     # prefill_chunk, padded) where the bucketed join prices
                     # the prefill at the prompt's own pow2 bucket — chunked
                     # prefill only earns its keep on multi-chunk prompts
-                    with sp("dispatch.join", cat="device"):
-                        self._join(req)
-                        if tr is not None:
-                            jax.block_until_ready(self._cur_tok)
+                    self._join(req)
                     req.prefilled = req.prompt_len
                     if not req.failed:      # poisoned pages never cached
                         self.scheduler.register_prefix(req)
                         self.last_step_tokens += 1     # the prefill token
         for req in self.scheduler.take_rejected():
             self._finish(req, finished)
-        with sp("plan_step", cat="sched"):
+        with span("sched.plan"):
             plan = self.scheduler.plan_step(decode_chunk,
                                             self.prefill_chunk or 1)
         # plan entries are validated and deduped before dispatch: a
@@ -610,20 +599,14 @@ class PagedEngine:
         step_rids.update(r.rid for r in decode_rs)
         # decode first: decode-ready slots are never stalled by prefill
         if decode_rs:
-            with sp("dispatch.decode", cat="device"):
-                self._decode_once(decode_rs, decode_chunk, use_spec)
-                if tr is not None:
-                    jax.block_until_ready(self._out_buf)
+            self._decode_once(decode_rs, decode_chunk, use_spec)
         for slot in plan.prefill_slots:
             r = running.get(slot)
             if r is None or r.prefill_done or r.cancelled \
                     or r.expired(now, self._sched_steps):
                 continue
             step_rids.add(r.rid)
-            with sp("dispatch.prefill", cat="device"):
-                self._prefill_one_chunk(r)
-                if tr is not None:
-                    jax.block_until_ready(self._cur_tok)
+            self._prefill_one_chunk(r)
         done_slots = [s for s, r in self.scheduler.running.items()
                       if r.done or r.failed or r.cancelled
                       or r.expired(now, self._sched_steps)]
@@ -631,7 +614,7 @@ class PagedEngine:
             # one host transfer covers every request finishing this step;
             # device state is NOT reset — the decode fns mask unoccupied
             # slots to scratch, and admission rewrites the row anyway
-            with sp("readback", cat="engine"):
+            with span("engine.readback"):
                 host_out = np.asarray(self._out_buf)
             for slot in done_slots:
                 req = self.scheduler.running[slot]
@@ -660,29 +643,41 @@ class PagedEngine:
             status = RequestStatus.TRUNCATED
         else:
             status = RequestStatus.DEADLINE_EXCEEDED
-        req.status = status
-        self._m_status[status].inc()
-        out.append(req)
+        with span("engine.finish", rid=req.rid, status=status.value):
+            req.status = status
+            self._m_status[status].inc()
+            out.append(req)
 
-    def _preempt_slot(self, slot: int, tr=None) -> None:
+    def _preempt_slot(self, slot: int) -> None:
         """Preempt one running slot: read back its sampled tokens (the
         rare sync preemption pays), hand them to the scheduler — which
         registers complete pages in the prefix tree and requeues the
         replacement — and clear any injected poison with the slot."""
         req = self.scheduler.running[slot]
-        host_out = np.asarray(self._out_buf)
+        with span("engine.readback"):
+            host_out = np.asarray(self._out_buf)
         emitted = host_out[slot, :req.generated].copy()
         new = self.scheduler.preempt(slot, emitted)
         self._clear_poison(slot)
-        if tr is not None:
-            tr.instant("preempt", cat="lifecycle",
-                       args={"rid": req.rid, "slot": slot,
-                             "kept_tokens": int(len(new.prior_tokens))})
+        instant("engine.preempt", rid=req.rid, slot=slot,
+                kept_tokens=int(len(new.prior_tokens)))
 
     def _clear_poison(self, slot: int) -> None:
         if self._poison_host[slot]:
             self._poison = self._poison.at[slot].set(0.0)
             self._poison_host[slot] = 0.0
+
+    def progress(self, rids) -> dict[int, tuple[int, int, int]]:
+        """rid -> (slot, prompt tokens cached, tokens sampled) of every
+        request in ``rids`` that holds a slot, as of the last step's
+        dispatch (its device work may still be running: :meth:`sync`)."""
+        return {r.rid: (r.slot, r.prefilled, r.generated)
+                for r in self.scheduler.running.values() if r.rid in rids}
+
+    def sync(self) -> None:
+        """Wait until everything the last step dispatched has run, so
+        the tokens it sampled are visible to the host."""
+        jax.block_until_ready((self.cache, self._out_buf))
 
     def generate(self, prompts, n_tokens: int, *, priorities=None,
                  deadline_s: float | None = None,
@@ -730,32 +725,35 @@ class PagedEngine:
         all in one jitted call per bucket length."""
         slot, L = req.slot, req.prompt_len
         bucket = self._bucket(L)
-        prompt = np.zeros((1, bucket), np.int32)
-        prompt[0, :L] = req.prompt
-        nb = KV.num_blocks(bucket, self.page_size)
-        pages = np.full(nb, KV.SCRATCH_PAGE, np.int32)
-        pages[:min(nb, len(req.pages))] = req.pages[:nb]
-        # the scope tag carries the jit variant (one trace per bucket),
-        # so resolution bytes x execution count attributes correctly
-        with self.obs.dram.scope(f"join[{bucket}]"):
-            res = self._get_join(bucket)(
-                self.params, self.cache, jnp.asarray(prompt),
-                jnp.int32(L), jnp.int32(slot), jnp.asarray(pages),
-                self._lengths, self._cur_tok, self._out_buf, self._hist,
-                self._next_key(), self._poison)
-        if self.sc.nan_guard:
-            (self.cache, self._lengths, self._cur_tok, self._out_buf,
-             self._hist, bad) = res
-            self._m_prefill_tokens.inc(L)
-            if bool(np.asarray(bad)):
-                req.failed = True
-                self._m_nan_trips.inc()
-                return
-        else:
-            (self.cache, self._lengths, self._cur_tok, self._out_buf,
-             self._hist) = res
-            self._m_prefill_tokens.inc(L)
-        req.generated = 1
+        with span("engine.join", rid=req.rid, bucket=bucket):
+            prompt = np.zeros((1, bucket), np.int32)
+            prompt[0, :L] = req.prompt
+            nb = KV.num_blocks(bucket, self.page_size)
+            pages = np.full(nb, KV.SCRATCH_PAGE, np.int32)
+            pages[:min(nb, len(req.pages))] = req.pages[:nb]
+            # the scope tag carries the jit variant (one trace per bucket),
+            # so resolution bytes x execution count attributes correctly
+            with self.obs.dram.scope(f"join[{bucket}]"):
+                res = self._get_join(bucket)(
+                    self.params, self.cache, jnp.asarray(prompt),
+                    jnp.int32(L), jnp.int32(slot), jnp.asarray(pages),
+                    self._lengths, self._cur_tok, self._out_buf, self._hist,
+                    self._next_key(), self._poison)
+            if self.sc.nan_guard:
+                (self.cache, self._lengths, self._cur_tok, self._out_buf,
+                 self._hist, bad) = res
+                self._m_prefill_tokens.inc(L)
+                with span("engine.readback"):
+                    bad = bool(np.asarray(bad))
+                if bad:
+                    req.failed = True
+                    self._m_nan_trips.inc()
+                    return
+            else:
+                (self.cache, self._lengths, self._cur_tok, self._out_buf,
+                 self._hist) = res
+                self._m_prefill_tokens.inc(L)
+            req.generated = 1
 
     def _get_join(self, bucket: int):
         if bucket not in self._joins:
@@ -823,43 +821,47 @@ class PagedEngine:
         """
         start, L = req.prefilled, req.prompt_len
         c_real = min(self.prefill_chunk, L - start)
-        # span width = pow2 bucket of the real remainder, not the full
-        # prefill_chunk: the final partial chunk of any prompt — and the
-        # short unshared tail after a prefix-cache hit — pays for the
-        # tokens it actually carries
-        C = 1
-        while C < c_real:
-            C *= 2
-        final = start + c_real >= L
-        tokens = np.zeros((1, C), np.int32)
-        tokens[0, :c_real] = req.prompt[start:start + c_real]
-        take_at = (L - 1 - start) if final else -1
-        with self.obs.dram.scope(f"prefill[{C}]"):
-            res = self._get_chunk_fn(C)(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.int32(start), self._block_tables,
-                self._lengths, jnp.int32(req.slot),
-                jnp.int32(start + c_real), jnp.int32(take_at),
-                self._cur_tok, self._out_buf, self._hist, self._next_key(),
-                self._poison)
-        if self.sc.nan_guard:
-            (self.cache, self._lengths, self._cur_tok, self._out_buf,
-             self._hist, bad) = res
-            self._m_prefill_tokens.inc(c_real)
-            req.prefilled = start + c_real
-            if bool(np.asarray(bad)):   # guard sync: one scalar per chunk
-                req.failed = True
-                self._m_nan_trips.inc()
-                return
-        else:
-            (self.cache, self._lengths, self._cur_tok, self._out_buf,
-             self._hist) = res
-            self._m_prefill_tokens.inc(c_real)
-            req.prefilled = start + c_real
-        if final:
-            req.generated = 1
-            self.scheduler.register_prefix(req)
-            self.last_step_tokens += 1             # the prefill token
+        with span("engine.prefill", rid=req.rid, start=start,
+                  tokens=c_real):
+            # span width = pow2 bucket of the real remainder, not the full
+            # prefill_chunk: the final partial chunk of any prompt — and the
+            # short unshared tail after a prefix-cache hit — pays for the
+            # tokens it actually carries
+            C = 1
+            while C < c_real:
+                C *= 2
+            final = start + c_real >= L
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0, :c_real] = req.prompt[start:start + c_real]
+            take_at = (L - 1 - start) if final else -1
+            with self.obs.dram.scope(f"prefill[{C}]"):
+                res = self._get_chunk_fn(C)(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.int32(start), self._block_tables,
+                    self._lengths, jnp.int32(req.slot),
+                    jnp.int32(start + c_real), jnp.int32(take_at),
+                    self._cur_tok, self._out_buf, self._hist, self._next_key(),
+                    self._poison)
+            if self.sc.nan_guard:
+                (self.cache, self._lengths, self._cur_tok, self._out_buf,
+                 self._hist, bad) = res
+                self._m_prefill_tokens.inc(c_real)
+                req.prefilled = start + c_real
+                with span("engine.readback"):   # guard sync: one scalar
+                    bad = bool(np.asarray(bad))
+                if bad:
+                    req.failed = True
+                    self._m_nan_trips.inc()
+                    return
+            else:
+                (self.cache, self._lengths, self._cur_tok, self._out_buf,
+                 self._hist) = res
+                self._m_prefill_tokens.inc(c_real)
+                req.prefilled = start + c_real
+            if final:
+                req.generated = 1
+                self.scheduler.register_prefix(req)
+                self.last_step_tokens += 1             # the prefill token
 
     def _get_chunk_fn(self, C: int):
         if C not in self._chunk_fns:
@@ -1095,57 +1097,61 @@ class PagedEngine:
         # over-length steps result-invariant)
         chunk = 1 << (int(remaining.max()) - 1).bit_length()
         chunk = int(min(decode_chunk, chunk))
-        if use_spec:
-            # each verify call emits 1..spec+1 tokens; size the scan for
-            # the token budget at full acceptance — zero acceptance just
-            # spreads a slot's budget over more scheduler visits instead
-            # of burning idle full-span model calls here
-            iters = -(-chunk // (self.spec + 1))
-            with self.obs.dram.scope(f"spec_decode[{iters}]"):
+        with span("engine.decode", slots=len(running), chunk=chunk,
+                  spec=use_spec):
+            if use_spec:
+                # each verify call emits 1..spec+1 tokens; size the scan for
+                # the token budget at full acceptance — zero acceptance just
+                # spreads a slot's budget over more scheduler visits instead
+                # of burning idle full-span model calls here
+                iters = -(-chunk // (self.spec + 1))
+                with self.obs.dram.scope(f"spec_decode[{iters}]"):
+                    (self._cur_tok, self.cache, self._lengths, self._out_buf,
+                     self._hist, emitted, calls, *badv) = self._decode_spec(
+                        self.params, self.cache, self._cur_tok,
+                        self._block_tables, self._lengths,
+                        jnp.asarray(occupied), jnp.asarray(remaining),
+                        jnp.asarray(out_idx), self._out_buf, self._hist,
+                        self._poison, chunk=iters)
+                # the one per-step readback: how far each slot actually got
+                with span("engine.readback"):
+                    emitted = np.asarray(emitted)
+                    bad = np.asarray(badv[0]).astype(bool) if guard else None
+                for r in running:
+                    n = int(emitted[r.slot])
+                    r.generated += n
+                    self.last_step_tokens += n
+                    if guard and bad[r.slot]:
+                        r.failed = True
+                        self._m_nan_trips.inc()
+                self._m_spec_calls.inc(int(calls))
+                self._m_spec_tokens.inc(int(emitted.sum()))
+                self._m_decode_tokens.inc(int(emitted.sum()))
+                return
+            with self.obs.dram.scope(f"decode[{chunk}]"):
+                res = self._decode(
+                    self.params, self.cache, self._cur_tok, self._block_tables,
+                    self._lengths, jnp.asarray(occupied),
+                    jnp.asarray(remaining), jnp.asarray(out_idx),
+                    self._out_buf, self._next_key(), self._poison, chunk=chunk)
+            if guard:
                 (self._cur_tok, self.cache, self._lengths, self._out_buf,
-                 self._hist, emitted, calls, *badv) = self._decode_spec(
-                    self.params, self.cache, self._cur_tok,
-                    self._block_tables, self._lengths,
-                    jnp.asarray(occupied), jnp.asarray(remaining),
-                    jnp.asarray(out_idx), self._out_buf, self._hist,
-                    self._poison, chunk=iters)
-            # the one per-step readback: how far each slot actually got
-            emitted = np.asarray(emitted)
-            bad = np.asarray(badv[0]).astype(bool) if guard else None
+                 stats) = res
+                with span("engine.readback"):   # the guard's per chunk
+                    stats = np.asarray(stats)
+                emitted, bad = stats[0], stats[1].astype(bool)
+                for r in running:
+                    n = int(emitted[r.slot])
+                    r.generated += n
+                    self.last_step_tokens += n
+                    self._m_decode_tokens.inc(n)
+                    if bad[r.slot]:
+                        r.failed = True
+                        self._m_nan_trips.inc()
+                return
+            (self._cur_tok, self.cache, self._lengths, self._out_buf) = res
             for r in running:
-                n = int(emitted[r.slot])
-                r.generated += n
-                self.last_step_tokens += n
-                if guard and bad[r.slot]:
-                    r.failed = True
-                    self._m_nan_trips.inc()
-            self._m_spec_calls.inc(int(calls))
-            self._m_spec_tokens.inc(int(emitted.sum()))
-            self._m_decode_tokens.inc(int(emitted.sum()))
-            return
-        with self.obs.dram.scope(f"decode[{chunk}]"):
-            res = self._decode(
-                self.params, self.cache, self._cur_tok, self._block_tables,
-                self._lengths, jnp.asarray(occupied),
-                jnp.asarray(remaining), jnp.asarray(out_idx),
-                self._out_buf, self._next_key(), self._poison, chunk=chunk)
-        if guard:
-            (self._cur_tok, self.cache, self._lengths, self._out_buf,
-             stats) = res
-            stats = np.asarray(stats)   # the guard's per-chunk readback
-            emitted, bad = stats[0], stats[1].astype(bool)
-            for r in running:
-                n = int(emitted[r.slot])
-                r.generated += n
-                self.last_step_tokens += n
-                self._m_decode_tokens.inc(n)
-                if bad[r.slot]:
-                    r.failed = True
-                    self._m_nan_trips.inc()
-            return
-        (self._cur_tok, self.cache, self._lengths, self._out_buf) = res
-        for r in running:
-            steps = min(chunk, r.max_new_tokens - r.generated)
-            r.generated += steps
-            self.last_step_tokens += steps
-            self._m_decode_tokens.inc(steps)
+                steps = min(chunk, r.max_new_tokens - r.generated)
+                r.generated += steps
+                self.last_step_tokens += steps
+                self._m_decode_tokens.inc(steps)

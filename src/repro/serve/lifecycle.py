@@ -16,18 +16,21 @@ chaos harness and the launch CLI:
   expected tail here).  The scheduler uses this to pick the cheapest
   victim among equal priorities.
 * :class:`DegradationController` — the pressure ladder.  Reads the
-  PR 8 metrics registry (p99 step latency, free-page watermark, queue
+  metrics registry (p99 step latency, free-page watermark, queue
   depth) and steps through ``no_spec`` (disable speculative decode) →
   ``small_chunk`` (halve the decode chunk) → ``preempt`` (allow
   preemption even when the config flag is off), with hysteresis in
-  both directions.  Each transition is a trace instant event and a
-  counter tick; the current rung is the ``degrade.level`` gauge.
+  both directions.  Each transition is an instant on the profiler's
+  trace (``degrade.up`` / ``degrade.down``) and a counter tick; the
+  current rung is the ``degrade.level`` gauge.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import enum
+
+from repro.obs.trace import instant
 
 
 class RequestStatus(enum.Enum):
@@ -117,10 +120,8 @@ class DegradationController:
 
     LEVELS = ("normal", "no_spec", "small_chunk", "preempt")
 
-    def __init__(self, registry, thresholds: DegradeThresholds | None = None,
-                 tracer=None):
+    def __init__(self, registry, thresholds: DegradeThresholds | None = None):
         self.thresholds = thresholds or DegradeThresholds()
-        self.tracer = tracer
         self.level = 0
         self._hot = 0
         self._cool = 0
@@ -173,11 +174,9 @@ class DegradationController:
         old = self.LEVELS[self.level]
         self.level = new_level
         (self._m_escalations if up else self._m_recoveries).inc()
-        if self.tracer is not None:
-            self.tracer.instant(
-                f"degrade.{'up' if up else 'down'}", cat="lifecycle",
-                args={"from": old, "to": self.LEVELS[new_level],
-                      "signal": signal})
+        instant(f"degrade.{'up' if up else 'down'}",
+                **{"from": old, "to": self.LEVELS[new_level],
+                   "signal": signal})
 
     # rung -> engine overrides -------------------------------------------------
 

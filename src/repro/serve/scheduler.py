@@ -61,11 +61,12 @@ exactly that tail).
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 
 import numpy as np
 
-from repro.obs.metrics import MetricsRegistry
+from repro.obs.metrics import DEFAULT_US_BUCKETS, MetricsRegistry
 from repro.serve.kv_cache import PageAllocator, num_blocks
 from repro.serve.lifecycle import replay_cost_tokens
 
@@ -98,6 +99,8 @@ class Request:
     failed: bool = False            # NaN guard / retry exhaustion -> FAILED
     status: object = None           # lifecycle.RequestStatus, terminal
     backoff: int = 0                # admission rounds until the next probe
+    t_submit: int | None = None     # engine-clock ns at (re)queueing
+    t_admit: int | None = None      # engine-clock ns at the latest admission
 
     def __post_init__(self):
         if self.orig_prompt_len < 0:
@@ -159,8 +162,9 @@ class Scheduler:
     def __init__(self, max_batch: int, page_size: int,
                  allocator: PageAllocator, max_seq: int,
                  age_limit: int = 8, prefix_cache=None, metrics=None,
-                 max_retries: int | None = None):
+                 max_retries: int | None = None, clock=time.monotonic_ns):
         self.max_batch = max_batch
+        self.clock = clock               # ns; the engine passes its own
         self.page_size = page_size
         self.allocator = allocator
         self.max_seq = max_seq
@@ -183,6 +187,9 @@ class Scheduler:
         self._m_rejected = m.counter("sched.rejected")
         self._m_expired = m.counter("sched.expired")
         self._m_rollbacks = m.counter("sched.admit_rollbacks")
+        self._m_queue_wait = m.histogram(
+            "sched.queue_wait_us",
+            DEFAULT_US_BUCKETS + (2_500_000.0, 10_000_000.0, 60_000_000.0))
 
     # -- queue ----------------------------------------------------------------
 
@@ -196,6 +203,8 @@ class Scheduler:
             raise ValueError(
                 f"request {req.rid}: needs {self.pages_needed(req)} pages "
                 f"but the pool holds {self.allocator.capacity}")
+        if req.t_submit is None:
+            req.t_submit = self.clock()
         self.waiting.append(req)
 
     def pages_needed(self, req: Request) -> int:
@@ -286,6 +295,9 @@ class Scheduler:
         req.pages = shared + fresh
         req.cached_tokens = matched
         req.prefilled = start               # prefill resumes at the boundary
+        req.t_admit = self.clock()
+        if req.t_submit is not None:
+            self._m_queue_wait.observe((req.t_admit - req.t_submit) / 1e3)
         self.running[req.slot] = req
         return req
 
@@ -452,7 +464,8 @@ class Scheduler:
             expire_step=req.expire_step, age=req.age,
             preempt_count=req.preempt_count + 1, prior_tokens=prior,
             orig_prompt_len=req.orig_prompt_len,
-            orig_max_new=req.orig_max_new, cancelled=req.cancelled)
+            orig_max_new=req.orig_max_new, cancelled=req.cancelled,
+            t_submit=self.clock())
         self.waiting.insert(min(1, len(self.waiting)), new)
         self._m_preemptions.inc()
         return new

@@ -9,6 +9,7 @@ serves both "fits in memory" and "needs accumulation" regimes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.obs.trace import span
 from repro.optim import adamw
 from repro.optim.compress import compress_tree
 from repro.train import checkpoint as ckpt
@@ -120,12 +122,12 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches, *,
     ``obs`` (an :class:`repro.obs.Obs` bundle, optional) gets the same
     telemetry the serving engines emit: ``train.loss`` /
     ``train.tokens_per_s`` gauges and a ``train.step_us`` histogram in
-    the registry, ``step``/``grad``/``checkpoint`` spans plus a
-    throughput counter track in the tracer, and — with
-    ``blocked_linear`` — every projection's schedule resolution in the
-    DRAM ledger under the ``train_step`` scope.  The loop already
-    fences every step on the loss, so spans time device work with or
-    without a tracer attached.
+    the registry, and — with ``blocked_linear`` — every projection's
+    schedule resolution in the DRAM ledger under the ``train_step``
+    scope.  Every step emits a ``train.step`` span and every save a
+    ``train.checkpoint`` span (recorded while a profiler session is
+    open).  The loop waits for every step's loss, which the straggler
+    watchdog times, so a ``train.step`` span covers the device work.
     """
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     if params is None:
@@ -141,8 +143,6 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches, *,
             log(f"restored checkpoint at step {start_step}")
 
     if obs is not None:
-        from repro.obs import null_span
-        span = obs.tracer.span if obs.tracer is not None else null_span
         g_loss = obs.registry.gauge("train.loss")
         g_tps = obs.registry.gauge("train.tokens_per_s")
         h_step = obs.registry.histogram("train.step_us")
@@ -153,15 +153,9 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches, *,
     history = []
     for step, batch in enumerate(batches, start=start_step):
         t0 = time.perf_counter()
-        if obs is not None:
-            with span(f"step {step}", cat="train",
-                      args={"step": step}):
-                with span("grad", cat="train"), \
-                        obs.dram.scope("train_step"):
-                    params, opt_state, metrics = step_fn(
-                        params, opt_state, batch)
-                    jax.block_until_ready(metrics["loss"])
-        else:
+        scope = (obs.dram.scope("train_step") if obs is not None
+                 else contextlib.nullcontext())
+        with span("train.step", step=step), scope:
             params, opt_state, metrics = step_fn(params, opt_state, batch)
             jax.block_until_ready(metrics["loss"])
         dt = time.perf_counter() - t0
@@ -176,21 +170,13 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches, *,
             h_step.observe(dt * 1e6)
             c_steps.inc()
             obs.dram.end_step()
-            if obs.tracer is not None:
-                obs.tracer.counter("train", {"loss": float(metrics["loss"]),
-                                             "tokens_per_s": tps})
         if step % tc.log_every == 0 or slow:
             log(f"step {step:5d} loss {float(metrics['loss']):.4f} "
                 f"gnorm {float(metrics.get('grad_norm', 0)):.3f} "
                 f"{dt*1e3:.0f}ms" + ("  [STRAGGLER]" if slow else ""))
         history.append(float(metrics["loss"]))
         if tc.ckpt_dir and (step + 1) % tc.ckpt_every == 0:
-            if obs is not None:
-                with span("checkpoint", cat="train",
-                          args={"step": step + 1}):
-                    ckpt.save_async(tc.ckpt_dir, step + 1,
-                                    {"params": params, "opt": opt_state})
-            else:
+            with span("train.checkpoint", step=step + 1):
                 ckpt.save_async(tc.ckpt_dir, step + 1,
                                 {"params": params, "opt": opt_state})
     ckpt.wait_async()

@@ -44,9 +44,9 @@ _default_cache = ScheduleCache()
 def set_default_cache(cache: ScheduleCache) -> ScheduleCache:
     """Swap the process-wide schedule cache; returns the previous one.
 
-    The profiler's ``--corrupt`` fault injection uses this to plant a
-    deliberately bad cached schedule and watch the fidelity gate catch
-    it; tests use it to isolate cache state.  Also drops the analytic
+    Fault injection (``chaos.inject.CorruptScheduleCache``) uses this
+    to plant a deliberately bad cached schedule; tests use it to isolate
+    cache state.  Also drops the analytic
     memo so the swap is visible to ops already traced once.
     """
     global _default_cache

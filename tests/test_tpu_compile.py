@@ -159,6 +159,20 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
     assert 'custom_call_target="tpu_custom_call"' in text, kernel
 
 
+def test_paged_attention_kernel_is_named_flash_decode(one_chip):
+    """A profiler trace shows the paged-attention kernel under the name
+    of its custom call: ``flash_decode``, in the decode and the
+    prefill-chunk forms alike."""
+    import re
+    for case in (case_flash_decode, case_flash_decode_prefill_chunk):
+        fn, shapes = case()
+        text = _compile(fn, one_chip, *shapes)
+        kernels = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                             r'custom_call_target="tpu_custom_call"', text)
+        assert kernels and all(re.fullmatch(r"flash_decode(\.\d+)?", k)
+                               for k in kernels), kernels
+
+
 def test_flash_attention_and_grad_compile_sharded_on_v5e_2x2(topo):
     """The training path's attention on a (data 2, model 2) mesh: GSPMD
     cannot partition a Mosaic kernel, so the model's attention must run
