@@ -311,16 +311,16 @@ def _paged_fns(cfg, page_size: int, max_seq: int, fuse: bool,
     @jax.jit
     def span(params, cache, tokens, start):
         with ops.fused_ops(fuse):
-            attn = KV.make_paged_span_step(cfg, bt, page_size, max_seq,
-                                           use_kernel, interpret)
+            attn = KV.make_paged_span_step(cfg, bt, max_seq, use_kernel,
+                                           interpret)
             return T.decode_step(cfg, params, tokens, cache,
                                  jnp.reshape(start, (1,)), attn_step=attn)
 
     @jax.jit
     def step(params, cache, tok, pos):
         with ops.fused_ops(fuse):
-            attn = KV.make_paged_attn_step(cfg, bt, page_size, use_kernel,
-                                           interpret, fused=fuse)
+            attn = KV.make_paged_attn_step(cfg, bt, use_kernel, interpret,
+                                           fused=fuse)
             return T.decode_step(cfg, params, tok, cache,
                                  jnp.reshape(pos, (1,)), attn_step=attn)
 

@@ -14,17 +14,20 @@ Layouts (GQA-native: all G query heads of one KV head share its pages):
 * ``q``:            (B, Hkv, G, D) — the current token's query rows;
 * ``k/v_pages``:    (n_pages, Hkv, page, D) — the global page pool, head-
   major inside a page so one kernel block ``(1, 1, page, D)`` is a
-  contiguous (sublane x lane) tile of one KV head;
+  contiguous (sublane x lane) tile of one KV head — or the stacked pools
+  of a layer group, (n_layers, n_pages, Hkv, page, D), with ``layer``
+  naming the layer: the kernel reads that layer's pages in place, so a
+  layer scan never cuts its pool out of the stack;
 * ``block_tables``: (B, n_blocks) int32 — physical page of each logical
   KV block; entries past a request's length must still be *valid* page
   indices (use 0) because the DMA runs before the mask is applied;
 * ``lengths``:      (B,) int32 — tokens in the cache *including* the one
-  being decoded (its K/V must already be scattered into the pages).
+  being decoded (its K/V must already be written into the pages).
 
 Grid is (B, Hkv, n_blocks) with the KV-block dim minor-most so the
-accumulators persist across the reduction; block tables and lengths ride
-in scalar-prefetch SMEM so the page DMA for block ``i`` of request ``b``
-is issued straight from ``block_tables[b, i]``.
+accumulators persist across the reduction; block tables, lengths and the
+layer index ride in scalar-prefetch SMEM so the page DMA for block ``i``
+of request ``b`` is issued straight from ``block_tables[b, i]``.
 """
 
 from __future__ import annotations
@@ -50,6 +53,25 @@ def page_pool_shape(n_pages: int, hkv: int, page: int,
                     head_dim: int) -> tuple[int, int, int, int]:
     """Shape of a K or V page pool as every kernel here reads it."""
     return (n_pages, hkv, page, head_dim)
+
+
+def layer_stack(k_pages: jax.Array, v_pages: jax.Array, layer):
+    """The pools as a layer stack and the layer index as a (1,) int32
+    scalar operand: a lone (n_pages, ...) pool is a stack of one (a free
+    reshape), so the kernels that read or write pages index one layout."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+    lay = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
+    return k_pages, v_pages, lay
+
+
+def _page_spec(k_pages: jax.Array) -> pl.BlockSpec:
+    """Block ``i`` of request ``bi``, KV head ``h``: page
+    ``bt[bi, i]`` of layer ``lay[0]`` of a stacked pool."""
+    page, d = k_pages.shape[-2:]
+    return pl.BlockSpec((None, 1, 1, page, d),
+                        lambda bi, h, i, bt, ln, lay: (lay[0], bt[bi, i], h,
+                                                       0, 0))
 
 
 def vmem_bytes_required(block_kv: int, groups: int, head_dim: int,
@@ -154,7 +176,7 @@ def _decode_finish(i, n_blocks, o_ref, m_ref, l_ref, acc_ref):
         o_ref[...] = (acc_ref[...] / safe_l)[None, None].astype(o_ref.dtype)
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(bt_ref, len_ref, lay_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float,
                    window: int | None, logit_cap: float | None,
                    block_kv: int, n_blocks: int, q_span: int = 1,
@@ -175,7 +197,7 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     _decode_finish(i, n_blocks, o_ref, m_ref, l_ref, acc_ref)
 
 
-def _decode_fp8_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
+def _decode_fp8_kernel(bt_ref, len_ref, lay_ref, q_ref, k_ref, v_ref, ks_ref,
                        vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
                        scale: float, window: int | None,
                        logit_cap: float | None, block_kv: int,
@@ -207,7 +229,8 @@ def _decode_fp8_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
 @functools.partial(jax.jit, static_argnames=("window", "logit_cap",
                                              "q_span", "interpret"))
 def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                 block_tables: jax.Array, lengths: jax.Array, *,
+                 block_tables: jax.Array, lengths: jax.Array,
+                 layer: jax.Array | int | None = None, *,
                  window: int | None = None,
                  logit_cap: float | None = None,
                  q_span: int = 1,
@@ -224,27 +247,28 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     prefill: the GQA grouping already streams a multi-row q block, so
     spanning positions costs no extra KV traffic.  Returns the same
     shape as ``q``.
+
+    With stacked (n_layers, n_pages, Hkv, page, D) pools, ``layer``
+    names the layer whose pages are read.
     """
     b, hkv, gtot, d = q.shape
     if gtot % q_span:
         raise ValueError(f"q rows {gtot} not divisible by q_span {q_span}")
     g = gtot // q_span
-    page = k_pages.shape[2]
+    k_pages, v_pages, lay = layer_stack(k_pages, v_pages, layer)
+    page = k_pages.shape[-2]
     n_blocks = block_tables.shape[1]
     scale = d ** -0.5
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, gtot, d),
-                         lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, gtot, d), lambda bi, h, i, *_: (bi, h, 0, 0)),
+            _page_spec(k_pages),
+            _page_spec(v_pages),
         ],
         out_specs=pl.BlockSpec((1, 1, gtot, d),
-                               lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
+                               lambda bi, h, i, *_: (bi, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((gtot, 1), jnp.float32),  # running max m
             pltpu.VMEM((gtot, 1), jnp.float32),  # running denom l
@@ -258,7 +282,7 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gtot, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), lay,
       q, k_pages, v_pages)
 
 
@@ -266,7 +290,8 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                              "q_span", "interpret"))
 def flash_decode_fp8(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      k_scale: jax.Array, v_scale: jax.Array,
-                     block_tables: jax.Array, lengths: jax.Array, *,
+                     block_tables: jax.Array, lengths: jax.Array,
+                     layer: jax.Array | int | None = None, *,
                      window: int | None = None,
                      logit_cap: float | None = None,
                      q_span: int = 1,
@@ -274,41 +299,40 @@ def flash_decode_fp8(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Paged attention over an fp8-quantized page pool.
 
     Same contract as :func:`flash_decode` (including the multi-position
-    ``q_span`` q block) except ``k_pages``/``v_pages`` are fp8
-    (``float8_e4m3fn``) and ``k_scale``/``v_scale`` are fp32 per-kv-head
-    dequantization scales of shape ``(Hkv,)`` (pass ones for a pure-cast
-    cache).  The pages stream from HBM at one byte per element;
-    dequantization happens in VMEM inside the kernel, so HBM traffic for
-    the dominant decode operand is halved vs bf16 — which is why the
-    page size comes from the ``"flash_decode_fp8"`` schedule key.
+    ``q_span`` q block and the ``layer`` of stacked pools) except
+    ``k_pages``/``v_pages`` are fp8 (``float8_e4m3fn``) and
+    ``k_scale``/``v_scale`` are fp32 per-kv-head dequantization scales of
+    shape ``(Hkv,)`` (pass ones for a pure-cast cache).  The pages stream
+    from HBM at one byte per element; dequantization happens in VMEM
+    inside the kernel, so HBM traffic for the dominant decode operand is
+    halved vs bf16 — which is why the page size comes from the
+    ``"flash_decode_fp8"`` schedule key.
     Returns the same shape as ``q`` in ``q.dtype``.
     """
     b, hkv, gtot, d = q.shape
     if gtot % q_span:
         raise ValueError(f"q rows {gtot} not divisible by q_span {q_span}")
     g = gtot // q_span
-    page = k_pages.shape[2]
+    k_pages, v_pages, lay = layer_stack(k_pages, v_pages, layer)
+    page = k_pages.shape[-2]
     n_blocks = block_tables.shape[1]
     scale = d ** -0.5
     ks = jnp.asarray(k_scale, jnp.float32).reshape(hkv)
     vs = jnp.asarray(v_scale, jnp.float32).reshape(hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, gtot, d),
-                         lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, gtot, d), lambda bi, h, i, *_: (bi, h, 0, 0)),
+            _page_spec(k_pages),
+            _page_spec(v_pages),
             # the (Hkv,) scale vectors sit whole in SMEM: a per-head
             # (1, 1) VMEM block would split the sublane dim
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, gtot, d),
-                               lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
+                               lambda bi, h, i, *_: (bi, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((gtot, 1), jnp.float32),  # running max m
             pltpu.VMEM((gtot, 1), jnp.float32),  # running denom l
@@ -322,7 +346,7 @@ def flash_decode_fp8(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gtot, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), lay,
       q, k_pages, v_pages, ks, vs)
 
 
@@ -382,8 +406,8 @@ def oproj_hbm_bytes(batch: int, hkv: int, groups: int, head_dim: int,
     return q_bytes + kv + wo + out
 
 
-def _decode_oproj_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, wo_ref,
-                         o_ref, m_ref, l_ref, acc_ref, oacc_ref, *,
+def _decode_oproj_kernel(bt_ref, len_ref, lay_ref, q_ref, k_ref, v_ref,
+                         wo_ref, o_ref, m_ref, l_ref, acc_ref, oacc_ref, *,
                          scale: float, window: int | None,
                          logit_cap: float | None, block_kv: int,
                          n_blocks: int, n_heads: int, groups: int,
@@ -440,19 +464,21 @@ def _decode_oproj_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, wo_ref,
                                              "interpret"))
 def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
                        v_pages: jax.Array, block_tables: jax.Array,
-                       lengths: jax.Array, wo: jax.Array, *,
+                       lengths: jax.Array, wo: jax.Array,
+                       layer: jax.Array | int | None = None, *,
                        window: int | None = None,
                        logit_cap: float | None = None,
                        interpret: bool = False) -> jax.Array:
     """Paged single-token attention fused with the output projection.
 
-    Same contract as :func:`flash_decode` plus ``wo``: the attention
-    output projection reshaped per kv head, ``(Hkv, G*D, E)`` (rows of
-    the dense ``(Hq*D, E)`` weight grouped by the kv head that produces
-    them).  Returns ``(B, E)`` — the per-head (G, D) attention outputs
-    are reduced into the projection inside VMEM and never round-trip
-    through HBM.  Schedule key: ``"flash_decode_oproj"`` (the KV block
-    is still the tunable, and still the paged cache's page size).
+    Same contract as :func:`flash_decode` (``layer`` included) plus
+    ``wo``: the attention output projection reshaped per kv head,
+    ``(Hkv, G*D, E)`` (rows of the dense ``(Hq*D, E)`` weight grouped by
+    the kv head that produces them).  Returns ``(B, E)`` — the per-head
+    (G, D) attention outputs are reduced into the projection inside VMEM
+    and never round-trip through HBM.  Schedule key:
+    ``"flash_decode_oproj"`` (the KV block is still the tunable, and
+    still the paged cache's page size).
 
     Traffic caveat (docs/fusion.md, "when fusion loses"): the output
     block is resident across the head loop of ONE batch row, so the wo
@@ -464,26 +490,24 @@ def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
     ``oproj_hbm_bytes`` exposes — leave ``fuse`` off there.
     """
     b, hkv, g, d = q.shape
-    page = k_pages.shape[2]
+    k_pages, v_pages, lay = layer_stack(k_pages, v_pages, layer)
+    page = k_pages.shape[-2]
     e = wo.shape[-1]
     assert wo.shape == (hkv, g * d, e), (wo.shape, (hkv, g * d, e))
     n_blocks = block_tables.shape[1]
     scale = d ** -0.5
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, h, i, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda bi, h, i, bt, ln: (bt[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, g * d, e), lambda bi, h, i, bt, ln: (h, 0, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda bi, h, i, *_: (bi, h, 0, 0)),
+            _page_spec(k_pages),
+            _page_spec(v_pages),
+            pl.BlockSpec((1, g * d, e), lambda bi, h, i, *_: (h, 0, 0)),
         ],
         # (B, 1, E) so the block's last two dims are whole: a (1, E)
         # block over (B, E) would split the sublane dim
-        out_specs=pl.BlockSpec((1, 1, e),
-                               lambda bi, h, i, bt, ln: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, e), lambda bi, h, i, *_: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),     # running max m
             pltpu.VMEM((g, 1), jnp.float32),     # running denom l
@@ -499,14 +523,15 @@ def flash_decode_oproj(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, e), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), lay,
       q, k_pages, v_pages, wo).reshape(b, e)
 
 
 def paged_attention_oproj_ref(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array,
                               block_tables: jax.Array,
-                              lengths: jax.Array, wo: jax.Array, *,
+                              lengths: jax.Array, wo: jax.Array,
+                              layer: jax.Array | int | None = None, *,
                               window: int | None = None,
                               logit_cap: float | None = None,
                               ) -> jax.Array:
@@ -515,7 +540,7 @@ def paged_attention_oproj_ref(q: jax.Array, k_pages: jax.Array,
     b, hkv, g, d = q.shape
     e = wo.shape[-1]
     attn = paged_attention_ref(q, k_pages, v_pages, block_tables,
-                               lengths, window=window,
+                               lengths, layer, window=window,
                                logit_cap=logit_cap)    # (B, Hkv, G, D)
     flat = attn.reshape(b, hkv * g * d).astype(jnp.float32)
     w2 = wo.reshape(hkv * g * d, e).astype(jnp.float32)
@@ -526,13 +551,16 @@ def paged_attention_oproj_ref(q: jax.Array, k_pages: jax.Array,
 def paged_attention_fp8_ref(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, k_scale: jax.Array,
                             v_scale: jax.Array, block_tables: jax.Array,
-                            lengths: jax.Array, *,
+                            lengths: jax.Array,
+                            layer: jax.Array | int | None = None, *,
                             window: int | None = None,
                             logit_cap: float | None = None,
                             q_span: int = 1) -> jax.Array:
     """jnp oracle for :func:`flash_decode_fp8`: dequantize the page pool
     in fp32, then the dense masked softmax of :func:`paged_attention_ref`.
     """
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     hkv = k_pages.shape[1]
     ks = jnp.asarray(k_scale, jnp.float32).reshape(1, hkv, 1, 1)
     vs = jnp.asarray(v_scale, jnp.float32).reshape(1, hkv, 1, 1)
@@ -544,7 +572,8 @@ def paged_attention_fp8_ref(q: jax.Array, k_pages: jax.Array,
 
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
                         v_pages: jax.Array, block_tables: jax.Array,
-                        lengths: jax.Array, *,
+                        lengths: jax.Array,
+                        layer: jax.Array | int | None = None, *,
                         window: int | None = None,
                         logit_cap: float | None = None,
                         q_span: int = 1) -> jax.Array:
@@ -553,8 +582,10 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     Bit-comparable semantics to :func:`flash_decode` (same masking rules
     — including the per-position rows of a ``q_span > 1`` block — and
     fp32 math); the correctness oracle in tests and the fast vectorized
-    path off-TPU.
+    path off-TPU.  ``layer`` picks one layer of stacked pools.
     """
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     b, hkv, gtot, d = q.shape
     g = gtot // q_span
     page = k_pages.shape[2]

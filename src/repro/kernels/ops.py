@@ -309,32 +309,33 @@ def qkv_fused(x: jax.Array, wq, wk, wv, *,
 
 def paged_attention_oproj(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, block_tables: jax.Array,
-                          lengths: jax.Array, wo, *,
+                          lengths: jax.Array, wo,
+                          layer: jax.Array | int | None = None, *,
                           window: int | None = None,
                           logit_cap: float | None = None,
                           use_kernel: bool | None = None,
                           interpret: bool | None = None) -> jax.Array:
     """Paged decode attention with the output projection fused in.
 
-    Same contract as :func:`paged_attention` plus ``wo`` — the dense
-    ``(Hq*D, E)`` output-projection weight — and returns ``(B, E)``:
-    the per-head attention outputs are reduced into the projection in
-    VMEM and never exist in HBM.  An fp8 page pool or a quantized
-    ``wo`` falls back to the unfused pair (``paged_attention`` +
-    :func:`linear`), so ``--fuse`` composes with every ``--quantize``
-    mode.
+    Same contract as :func:`paged_attention` (``layer`` included) plus
+    ``wo`` — the dense ``(Hq*D, E)`` output-projection weight — and
+    returns ``(B, E)``: the per-head attention outputs are reduced into
+    the projection in VMEM and never exist in HBM.  An fp8 page pool or
+    a quantized ``wo`` falls back to the unfused pair
+    (``paged_attention`` + :func:`linear`), so ``--fuse`` composes with
+    every ``--quantize`` mode.
     """
     from repro.kernels.flash_decode import (flash_decode_oproj,
                                             paged_attention_oproj_ref)
     from repro.quant.quantize import QuantizedTensor
     b, hq, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     fp8 = jnp.dtype(k_pages.dtype).itemsize == 1
     if fp8 or isinstance(wo, QuantizedTensor):
         out = paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                              window=window, logit_cap=logit_cap,
+                              layer, window=window, logit_cap=logit_cap,
                               use_kernel=use_kernel, interpret=interpret)
         return linear(out.reshape(b, hq * d), wo, interpret)
     e = wo.shape[-1]
@@ -343,11 +344,11 @@ def paged_attention_oproj(q: jax.Array, k_pages: jax.Array,
     if _attn_kernels_on(use_kernel):
         interpret = default_interpret() if interpret is None else interpret
         return flash_decode_oproj(qg, k_pages, v_pages, block_tables,
-                                  lengths, wo3, window=window,
+                                  lengths, wo3, layer, window=window,
                                   logit_cap=logit_cap,
                                   interpret=interpret)
     return paged_attention_oproj_ref(qg, k_pages, v_pages, block_tables,
-                                     lengths, wo3, window=window,
+                                     lengths, wo3, layer, window=window,
                                      logit_cap=logit_cap)
 
 
@@ -512,7 +513,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array, *,
+                    block_tables: jax.Array, lengths: jax.Array,
+                    layer: jax.Array | int | None = None, *,
                     window: int | None = None,
                     logit_cap: float | None = None,
                     k_scale: jax.Array | None = None,
@@ -524,7 +526,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     q: (B, Hq, D) — the current token's query rows; k/v_pages:
     (n_pages, Hkv, page, D); block_tables: (B, n_blocks) physical page
     per logical KV block; lengths: (B,) cache length per request
-    *including* the token being decoded.  Returns (B, Hq, D).
+    *including* the token being decoded.  Returns (B, Hq, D).  Stacked
+    (n_layers, n_pages, Hkv, page, D) pools take the ``layer`` to read.
 
     A 4-D ``q`` of shape (B, S, Hq, D) is the multi-position form
     (speculative verify / chunked prefill): the S positions are
@@ -558,7 +561,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     else:
         b, hq, d = q.shape
         span = 1
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     if multi:
@@ -584,23 +587,48 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         interpret = default_interpret() if interpret is None else interpret
         if fp8:
             out = flash_decode_fp8(qg, k_pages, v_pages, ks, vs,
-                                   block_tables, lengths, window=window,
-                                   logit_cap=logit_cap, q_span=span,
-                                   interpret=interpret)
+                                   block_tables, lengths, layer,
+                                   window=window, logit_cap=logit_cap,
+                                   q_span=span, interpret=interpret)
         else:
             out = flash_decode(qg, k_pages, v_pages, block_tables, lengths,
-                               window=window, logit_cap=logit_cap,
+                               layer, window=window, logit_cap=logit_cap,
                                q_span=span, interpret=interpret)
     elif fp8 and scaled:
         out = paged_attention_fp8_ref(qg, k_pages, v_pages, ks, vs,
-                                      block_tables, lengths, window=window,
-                                      logit_cap=logit_cap, q_span=span)
+                                      block_tables, lengths, layer,
+                                      window=window, logit_cap=logit_cap,
+                                      q_span=span)
     else:
         out = paged_attention_ref(qg, k_pages, v_pages, block_tables,
-                                  lengths, window=window,
+                                  lengths, layer, window=window,
                                   logit_cap=logit_cap, q_span=span)
     if multi:
         return (out.reshape(b, hkv, span, g, d)
                    .transpose(0, 2, 1, 3, 4)
                    .reshape(b, span, hq, d))
     return out.reshape(b, hq, d)
+
+
+def write_kv(k_pages: jax.Array, v_pages: jax.Array, k: jax.Array,
+             v: jax.Array, block_tables: jax.Array, pos: jax.Array,
+             layer: jax.Array | int | None = None, *,
+             max_seq: int | None = None,
+             use_kernel: bool | None = None,
+             interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
+    """Write new K/V rows (B, S, Hkv, D) at positions ``pos[b] + t``
+    into the page pools (one layer's, or the ``layer`` of stacked
+    pools); returns the updated pools.
+
+    The aliased Pallas writer (``kernels.kv_write``) runs on TPU, the
+    one-scatter jnp oracle elsewhere — the same policy as
+    :func:`paged_attention`, whose pools it writes.  The oracle is no TPU
+    path: inside a hot program its scatter re-lays the pool.
+    """
+    from repro.kernels.kv_write import write_kv as _kernel, write_kv_ref
+    if _kernels_on(use_kernel):
+        interpret = default_interpret() if interpret is None else interpret
+        return _kernel(k_pages, v_pages, k, v, block_tables, pos, layer,
+                       max_seq=max_seq, interpret=interpret)
+    return write_kv_ref(k_pages, v_pages, k, v, block_tables, pos, layer,
+                        max_seq=max_seq)

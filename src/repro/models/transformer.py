@@ -410,15 +410,16 @@ def cache_shapes(cfg: ModelConfig, batch: int, max_seq: int,
 
 def _block_decode(cfg: ModelConfig, p: dict, h: jax.Array, mixer: str,
                   cache: dict, pos: jax.Array,
-                  attn_step=None) -> tuple[jax.Array, dict]:
+                  attn_step=None, layer=None) -> tuple[jax.Array, dict]:
     """One block's decode step.
 
     ``attn_step`` swaps the attention-layer implementation: it receives
-    ``(params, hn, cache, pos, window)`` and returns ``(out, new cache
-    entries)``.  The default is the dense per-request cache
+    ``(params, hn, cache, pos, window, layer)`` and returns ``(out, new
+    cache entries)``.  The default is the dense per-request cache
     (``L.attention_decode``); the serving subsystem passes the paged
     flash-decode step (``serve.kv_cache.make_paged_attn_step``).  The
-    recurrent / SSD / FFN structure is shared by both paths.
+    recurrent / SSD / FFN structure is shared by both paths.  ``layer``
+    is set when ``cache`` holds a layer group's stacked page pools.
     """
     hn = L.rmsnorm(p["norm1"], h)
     new_cache = dict(cache)
@@ -430,7 +431,8 @@ def _block_decode(cfg: ModelConfig, p: dict, h: jax.Array, mixer: str,
                                                attn_cache, pos,
                                                window=window)
         else:
-            out, attn_new = attn_step(p["mixer"], hn, cache, pos, window)
+            out, attn_new = attn_step(p["mixer"], hn, cache, pos, window,
+                                      layer)
         h = h + out
         new_cache.update(attn_new)
     elif mixer == "recurrent":
@@ -506,12 +508,19 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array,
     pattern = cfg.layer_pattern
 
     def scan_step(carry, xs):
-        # the stacked caches ride in the carry and each layer's slice is
-        # written back in place: as scan outputs they would be a second
-        # whole copy of every cache (pools are most of device memory)
+        # the stacked caches ride in the carry: as scan outputs they would
+        # be a second whole copy of every cache (pools are most of device
+        # memory).  Page pools go to attn_step whole with the layer index
+        # and come back whole — the pool writer and flash_decode index
+        # the stack in place (docs/serving.md, "The pool contract");
+        # every other cache has its layer's slice cut out and written back.
         h, caches = carry
         cycle_params, i = xs
         for j, mixer in enumerate(pattern):
+            if attn_step is not None and "k_pages" in caches[j]:
+                h, caches[j] = _block_decode(cfg, cycle_params[j], h, mixer,
+                                             caches[j], pos, attn_step, i)
+                continue
             layer = jax.tree.map(lambda c: c[i], caches[j])
             h, nc = _block_decode(cfg, cycle_params[j], h, mixer,
                                   layer, pos, attn_step)

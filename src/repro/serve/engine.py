@@ -874,8 +874,8 @@ class PagedEngine:
                                                       slot, 1)
                 with ops.fused_ops(sc.fuse):
                     attn = KV.make_paged_span_step(
-                        cfg, bt_row, self.page_size, sc.max_seq,
-                        sc.use_kernel, sc.interpret)
+                        cfg, bt_row, sc.max_seq, sc.use_kernel,
+                        sc.interpret)
                     logits, cache = T.decode_step(
                         cfg, params, tokens, cache,
                         jnp.full((1,), start, jnp.int32), attn_step=attn)
@@ -933,7 +933,7 @@ class PagedEngine:
         block_tables = jnp.where(occupied[:, None], block_tables,
                                  KV.SCRATCH_PAGE)
         lengths = jnp.where(occupied, lengths, 0)
-        attn = KV.make_paged_attn_step(cfg, block_tables, self.page_size,
+        attn = KV.make_paged_attn_step(cfg, block_tables,
                                        self.sc.use_kernel,
                                        self.sc.interpret,
                                        fused=self.sc.fuse)
@@ -1008,8 +1008,8 @@ class PagedEngine:
         block_tables = jnp.where(occupied[:, None], block_tables,
                                  KV.SCRATCH_PAGE)
         lengths = jnp.where(occupied, lengths, 0)
-        attn = KV.make_paged_span_step(cfg, block_tables, self.page_size,
-                                       max_seq, self.sc.use_kernel,
+        attn = KV.make_paged_span_step(cfg, block_tables, max_seq,
+                                       self.sc.use_kernel,
                                        self.sc.interpret)
 
         def drafts_for(hist, lengths):

@@ -273,17 +273,22 @@ def write_prefill(cfg: ModelConfig, paged: dict, dense: dict,
 
 
 def make_paged_attn_step(cfg: ModelConfig, block_tables: jax.Array,
-                         page_size: int, use_kernel: bool | None = None,
+                         use_kernel: bool | None = None,
                          interpret: bool | None = None,
                          fused: bool = False):
     """The ``attn_step`` the paged engine threads through
     ``transformer.decode_step``.
 
     ``pos`` arrives as the per-request cached-token count (B,): the new
-    token sits at position ``pos[b]``, its K/V are scattered into page
-    ``block_tables[b, pos // page]`` slot ``pos % page``, and attention
-    runs over ``pos + 1`` positions through ``ops.paged_attention``
-    (the flash-decode kernel / its oracle).
+    token sits at position ``pos[b]``, its K/V are written into page
+    ``block_tables[b, pos // page]`` row ``pos % page`` by
+    ``ops.write_kv`` (the aliased pool writer / its oracle), and
+    attention runs over ``pos + 1`` positions through
+    ``ops.paged_attention`` (the flash-decode kernel / its oracle).
+    ``cache`` holds one layer's pools, or a layer group's stacked pools
+    with ``layer`` naming the layer: both ops index the stack in place
+    and the updated stack is returned whole (docs/serving.md, "The pool
+    contract").
 
     ``fused=True`` (the engine's ``fuse`` flag) routes attention +
     output projection through ``ops.paged_attention_oproj`` — the
@@ -293,27 +298,23 @@ def make_paged_attn_step(cfg: ModelConfig, block_tables: jax.Array,
     from repro.kernels import ops
 
     def attn_step(p: dict, hn: jax.Array, cache: dict, pos: jax.Array,
-                  window: int | None):
+                  window: int | None, layer=None):
         b, _, _ = hn.shape
         hq, hd = cfg.n_heads, cfg.head_dim
         q, k, v = L.qkv_decode_proj(cfg, p, hn[:, 0], pos[:, None])
-
-        rows = jnp.arange(b)
-        page_idx = block_tables[rows, pos // page_size]
-        slot_idx = pos % page_size
-        kp = cache["k_pages"].at[page_idx, :, slot_idx].set(
-            k.astype(cache["k_pages"].dtype))
-        vp = cache["v_pages"].at[page_idx, :, slot_idx].set(
-            v.astype(cache["v_pages"].dtype))
+        kp, vp = ops.write_kv(cache["k_pages"], cache["v_pages"],
+                              k[:, None], v[:, None], block_tables, pos,
+                              layer, use_kernel=use_kernel,
+                              interpret=interpret)
 
         if fused:
             out = ops.paged_attention_oproj(
-                q, kp, vp, block_tables, pos + 1, p["wo"],
+                q, kp, vp, block_tables, pos + 1, p["wo"], layer,
                 window=window, logit_cap=cfg.attn_logit_cap,
                 use_kernel=use_kernel, interpret=interpret)
             out = out[:, None, :].astype(hn.dtype)
             return out, {"k_pages": kp, "v_pages": vp}
-        out = ops.paged_attention(q, kp, vp, block_tables, pos + 1,
+        out = ops.paged_attention(q, kp, vp, block_tables, pos + 1, layer,
                                   window=window,
                                   logit_cap=cfg.attn_logit_cap,
                                   use_kernel=use_kernel,
@@ -326,21 +327,21 @@ def make_paged_attn_step(cfg: ModelConfig, block_tables: jax.Array,
 
 
 def make_paged_span_step(cfg: ModelConfig, block_tables: jax.Array,
-                         page_size: int, max_seq: int,
-                         use_kernel: bool | None = None,
+                         max_seq: int, use_kernel: bool | None = None,
                          interpret: bool | None = None):
     """The span-capable ``attn_step`` for multi-token
     ``transformer.decode_step`` — one definition behind both chunked
     prefill and speculative verify.
 
     ``hn`` is (B, S, D): S consecutive tokens starting at position
-    ``pos[b]`` (= the cached length).  All S positions' K/V are
-    scattered into the request's pages first, then ONE
-    ``ops.paged_attention`` call with a (B, S, Hq, D) q block scores
+    ``pos[b]`` (= the cached length).  All S positions' K/V are written
+    into the request's pages first (``ops.write_kv``, as in
+    :func:`make_paged_attn_step`, stacked pools and ``layer`` too), then
+    ONE ``ops.paged_attention`` call with a (B, S, Hq, D) q block scores
     every position under its own causal mask — the kernel streams each
     KV page once for all S rows.  Positions at or past ``max_seq`` (the
     padded tail of a final prefill chunk, or draft rows past the token
-    budget) scatter harmlessly into the scratch page; positions inside
+    budget) are dropped; positions inside
     ``max_seq`` but past the span's accepted prefix are overwritten by
     the next span before the length mask ever exposes them.
 
@@ -351,24 +352,16 @@ def make_paged_span_step(cfg: ModelConfig, block_tables: jax.Array,
     from repro.kernels import ops
 
     def attn_step(p: dict, hn: jax.Array, cache: dict, pos: jax.Array,
-                  window: int | None):
+                  window: int | None, layer=None):
         b, s, _ = hn.shape
         hq, hd = cfg.n_heads, cfg.head_dim
         positions = pos[:, None] + jnp.arange(s, dtype=pos.dtype)[None, :]
         q, k, v = L.qkv_span_proj(cfg, p, hn, positions)
+        kp, vp = ops.write_kv(cache["k_pages"], cache["v_pages"], k, v,
+                              block_tables, pos, layer, max_seq=max_seq,
+                              use_kernel=use_kernel, interpret=interpret)
 
-        rows = jnp.arange(b)[:, None]
-        nb = block_tables.shape[1]
-        safe = positions < max_seq
-        blk = jnp.minimum(positions // page_size, nb - 1)
-        page_idx = jnp.where(safe, block_tables[rows, blk], SCRATCH_PAGE)
-        slot_idx = jnp.where(safe, positions % page_size, 0)
-        kp = cache["k_pages"].at[page_idx, :, slot_idx].set(
-            k.astype(cache["k_pages"].dtype))
-        vp = cache["v_pages"].at[page_idx, :, slot_idx].set(
-            v.astype(cache["v_pages"].dtype))
-
-        out = ops.paged_attention(q, kp, vp, block_tables, pos + 1,
+        out = ops.paged_attention(q, kp, vp, block_tables, pos + 1, layer,
                                   window=window,
                                   logit_cap=cfg.attn_logit_cap,
                                   use_kernel=use_kernel,

@@ -47,6 +47,144 @@ def test_flash_decode_kernel_matches_oracle(window, logit_cap):
                                rtol=1e-5, atol=1e-5)
 
 
+# ================ pools in place: aliased writer, layer index ================
+
+
+def _pools(rng, dtype, shape):
+    def one():
+        return jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
+    return one(), one()
+
+
+@pytest.mark.parametrize("dtype,stacked,B,S,page,nb,pos,max_seq", [
+    # one token per slot; slots 1 and 3 inactive: their block-table rows
+    # are masked to the scratch page and their lengths to 0
+    (jnp.bfloat16, True, 4, 1, 32, 2, [5, 0, 63, 0], None),
+    # a span crossing 16-row tiles and a page boundary, partial at both
+    # ends, beside a one-page-aligned span
+    (jnp.bfloat16, True, 2, 40, 32, 4, [21, 64], None),
+    # a span running past max_seq (the padded tail of a last chunk)
+    (jnp.bfloat16, False, 2, 12, 32, 2, [58, 3], 64),
+    # fp8 pools: 32-row tiles
+    (jnp.float8_e4m3fn, True, 2, 40, 64, 3, [5, 150], None),
+    (jnp.float8_e4m3fn, False, 3, 1, 64, 2, [0, 77, 127], None),
+    # fp32 pools with pages smaller than a tile
+    (jnp.float32, False, 3, 3, 4, 4, [0, 6, 14], None),
+], ids=["bf16-decode-inactive", "bf16-span-tiles-pages",
+        "bf16-span-past-max-seq", "fp8-span", "fp8-decode",
+        "f32-small-pages"])
+def test_kv_writer_matches_scatter_oracle(dtype, stacked, B, S, page, nb,
+                                          pos, max_seq):
+    """The aliased Pallas writer (interpret mode) leaves every real page
+    bit-identical to the ``.at[].set`` oracle; only the scratch page,
+    which no request reads, may differ."""
+    from repro.kernels import ops
+    rng = np.random.default_rng(7)
+    hkv, D, layers = 2, 128, 3
+    n_pages = B * nb + 1
+    shape = ((layers,) if stacked else ()) + (n_pages, hkv, page, D)
+    kp, vp = _pools(rng, dtype, shape)
+    k = jnp.asarray(rng.normal(size=(B, S, hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, S, hkv, D)), jnp.float32)
+    bt = np.asarray(1 + rng.permutation(B * nb).reshape(B, nb), np.int32)
+    pos = np.asarray(pos, np.int32)
+    if S == 1 and B == 4:
+        bt[[1, 3]] = KV.SCRATCH_PAGE
+    layer = 1 if stacked else None
+    args = (kp, vp, k, v, jnp.asarray(bt), jnp.asarray(pos), layer)
+    got = ops.write_kv(*args, max_seq=max_seq, use_kernel=True,
+                       interpret=True)
+    want = ops.write_kv(*args, max_seq=max_seq, use_kernel=False)
+    real = (slice(None), slice(1, None)) if stacked else (slice(1, None),)
+    for g, w, old in zip(got, want, (kp, vp)):
+        g, w = (np.asarray(x.astype(jnp.float32)) for x in (g, w))
+        np.testing.assert_array_equal(g[real], w[real])
+        assert not np.array_equal(g[real], np.asarray(
+            old.astype(jnp.float32))[real])      # something was written
+
+
+@pytest.mark.parametrize("kernel", ["flash_decode", "flash_decode_fp8",
+                                    "flash_decode_oproj"])
+def test_flash_decode_reads_stacked_pools_by_layer(kernel):
+    """Each decode kernel over stacked pools with a layer index returns
+    bit-for-bit what it returns over that layer's pool alone, and its
+    oracle does the same."""
+    from repro.kernels import flash_decode as FD
+    rng = np.random.default_rng(8)
+    B, hkv, G, D, page, nb, layers, E = 3, 2, 3, 16, 8, 4, 3, 24
+    fp8 = kernel == "flash_decode_fp8"
+    dtype = jnp.float8_e4m3fn if fp8 else jnp.float32
+    kp, vp = _pools(rng, dtype, (layers, B * nb + 1, hkv, page, D))
+    q = jnp.asarray(rng.normal(size=(B, hkv, G, D)), jnp.float32)
+    bt = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb), jnp.int32)
+    lengths = jnp.asarray([1, 13, 32], jnp.int32)
+    if kernel == "flash_decode_oproj":
+        wo = jnp.asarray(rng.normal(size=(hkv, G * D, E)), jnp.float32)
+        run = lambda k, v, *lay: FD.flash_decode_oproj(
+            q, k, v, bt, lengths, wo, *lay, interpret=True)
+        ref = lambda k, v, *lay: FD.paged_attention_oproj_ref(
+            q, k, v, bt, lengths, wo, *lay)
+    elif fp8:
+        ks = jnp.asarray([0.5, 2.0], jnp.float32)
+        vs = jnp.asarray([1.5, 0.25], jnp.float32)
+        run = lambda k, v, *lay: FD.flash_decode_fp8(
+            q, k, v, ks, vs, bt, lengths, *lay, interpret=True)
+        ref = lambda k, v, *lay: FD.paged_attention_fp8_ref(
+            q, k, v, ks, vs, bt, lengths, *lay)
+    else:
+        run = lambda k, v, *lay: FD.flash_decode(
+            q, k, v, bt, lengths, *lay, window=5, interpret=True)
+        ref = lambda k, v, *lay: FD.paged_attention_ref(
+            q, k, v, bt, lengths, *lay, window=5)
+    for layer in range(layers):
+        for fn in (run, ref):
+            np.testing.assert_array_equal(
+                np.asarray(fn(kp, vp, jnp.int32(layer))),
+                np.asarray(fn(kp[layer], vp[layer])))
+
+
+@pytest.mark.parametrize("arch,n_layers,kw", [
+    ("gemma2-9b", 5, {}),
+    ("gemma2-9b", 5, {"spec_decode": 2}),
+    ("gemma2-9b", 5, {"fuse": True}),
+    ("recurrentgemma-9b", 7, {}),
+], ids=["local-global-tail", "spec-decode", "fused", "hybrid-tail"])
+def test_paged_engine_kernels_keep_pools_in_place(arch, n_layers, kw):
+    """The engine with the Pallas writer and layer-indexed flash_decode
+    forced on (interpret mode) serves the dense engine's greedy tokens,
+    token for token: stacked pools written and read in place, tail layers
+    on their own 4-D pools, recurrent states sliced per layer, chunked
+    prefill and speculative verify through the span writer.  Random
+    weights make greedy tokens blind to most attention faults, so the
+    caches it leaves must also match the jnp oracle engine's: the K/V of
+    every layer above the first is a function of the attention below."""
+    cfg = dataclasses.replace(_cfg(arch), n_layers=n_layers)
+    params = T.init_params(cfg, jax.random.PRNGKey(4))
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab, (L,)).astype(np.int32)
+               for L in (5, 19)]
+    dense = DecodeEngine(cfg, params, ServeConfig(max_seq=32))
+    ref = [dense.generate(p[None, :], 6)[0] for p in prompts]
+    caches = []
+    for use_kernel in (True, False):
+        paged = PagedEngine(cfg, params, PagedServeConfig(
+            max_seq=32, max_batch=2, page_size=8, decode_chunk=3,
+            prefill_chunk=8, use_kernel=use_kernel, interpret=True, **kw))
+        out = paged.generate(prompts, 6)
+        for a, b in zip(ref, out):
+            np.testing.assert_array_equal(a, b)
+        caches.append(paged.cache)
+    kernel_leaves = jax.tree_util.tree_flatten_with_path(caches[0])[0]
+    for (path, got), want in zip(kernel_leaves, jax.tree.leaves(caches[1])):
+        name = jax.tree_util.keystr(path)
+        got, want = np.asarray(got), np.asarray(want)
+        if "pages" in name:      # the scratch page holds no request's K/V
+            real = (slice(None),) * (got.ndim - 4) + (slice(1, None),)
+            got, want = got[real], want[real]
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
+
+
 def test_paged_attention_matches_dense_attention_decode():
     """ops.paged_attention == layers.attention_decode on the same cache
     content (the paged layout is a pure re-indexing of the dense one)."""
@@ -126,7 +264,7 @@ def test_paged_decode_logits_match_dense(arch):
     for t in range(L, L + steps):
         log_d, cache_d = T.decode_step(cfg, params, toks[:, t], cache_d,
                                        jnp.int32(t))
-        attn = KV.make_paged_attn_step(cfg, block_tables, page)
+        attn = KV.make_paged_attn_step(cfg, block_tables)
         log_p, paged = T.decode_step(cfg, params, toks[:, t], paged,
                                      lengths, attn_step=attn)
         lengths = lengths + 1

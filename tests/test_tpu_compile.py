@@ -23,7 +23,9 @@ from repro.serve import kv_cache as KV
 from repro.tune import best_schedule
 
 MAX_SEQ = 4096
-SLOTS = 8
+SLOTS = 8               # the page pool holds SLOTS whole contexts
+DECODE_SLOTS = 32       # request slots of a decode program
+POOL_LAYERS = 4         # layers of the stacked pools in a step program
 HKV, G, D, E, F = (CONFIG.n_kv_heads, CONFIG.n_heads // CONFIG.n_kv_heads,
                    CONFIG.head_dim, CONFIG.d_model, CONFIG.d_ff)
 
@@ -104,6 +106,27 @@ def case_flash_decode_oproj():
                                 _pool(page),
                                 ((SLOTS, MAX_SEQ // page), I32),
                                 ((SLOTS,), I32), ((HKV, G * D, E), BF16)]
+
+
+def _kv_write(b: int, s: int):
+    from repro.kernels.kv_write import write_kv
+    page = KV.choose_page_size(CONFIG, MAX_SEQ)
+    pool, dt = _pool(page)
+    stacked = ((POOL_LAYERS,) + pool, dt)
+    fn = lambda kp, vp, k, v, bt, pos, layer: write_kv(kp, vp, k, v, bt,
+                                                       pos, layer)
+    return fn, [stacked, stacked, ((b, s, HKV, D), BF16),
+                ((b, s, HKV, D), BF16), ((b, MAX_SEQ // page), I32),
+                ((b,), I32), ((), I32)]
+
+
+def case_kv_write():
+    return _kv_write(DECODE_SLOTS, 1)
+
+
+def case_kv_write_prefill_chunk():
+    page = KV.choose_page_size(CONFIG, MAX_SEQ)
+    return _kv_write(1, KV.choose_prefill_chunk(CONFIG, MAX_SEQ, page))
 
 
 def case_qkv_fused():
@@ -201,3 +224,101 @@ def test_flash_attention_and_grad_compile_sharded_on_v5e_2x2(topo):
     finally:
         set_axis_mapping(was)
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
+
+
+# ops that copy a pool: a slice, a relayout, an update of one and a scatter,
+# loose or fused
+_POOL_COPIES = {"copy", "copy-start", "copy-done", "dynamic-slice",
+                "dynamic-update-slice", "scatter", "fusion"}
+
+
+@pytest.fixture(scope="module")
+def step_program(one_chip):
+    """The engine's paged step programs at granite widths, POOL_LAYERS
+    layers, compiled for the described chip: ``steps`` decode steps over
+    DECODE_SLOTS slots in one scan (the decode program runs a chunk of
+    them), or one prefill chunk of ``span`` tokens for one request.
+    Returns (compiled, page, n_pages); compiles each shape once."""
+    from repro.models import transformer as T
+    from repro.models.base import build
+    cfg = dataclasses.replace(CONFIG, n_layers=POOL_LAYERS)
+    page = KV.choose_page_size(cfg, MAX_SEQ)
+    n_pages = _pool(page)[0][0]
+    on_chip = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = on_chip(T.param_shapes(cfg))
+    done = {}
+
+    def compile_(span: int, steps: int = 1):
+        if (span, steps) in done:
+            return done[span, steps]
+        b = DECODE_SLOTS if span == 1 else 1
+        cache = on_chip(build(KV.paged_cache_defs(cfg, b, n_pages, page),
+                              "shape"))
+        tok_shape = (b,) if span == 1 else (b, span)
+        scalars = on_chip([jax.ShapeDtypeStruct(tok_shape, I32),
+                           jax.ShapeDtypeStruct((b, MAX_SEQ // page), I32),
+                           jax.ShapeDtypeStruct((b,), I32)])
+
+        def step(params, cache, tok, bt, lengths):
+            if span > 1:
+                attn = KV.make_paged_span_step(cfg, bt, MAX_SEQ,
+                                               use_kernel=True,
+                                               interpret=False)
+                return T.decode_step(cfg, params, tok, cache, lengths,
+                                     attn_step=attn)
+            attn = KV.make_paged_attn_step(cfg, bt, use_kernel=True,
+                                           interpret=False)
+
+            def body(carry, _):
+                tok, cache, lengths = carry
+                logits, cache = T.decode_step(cfg, params, tok, cache,
+                                              lengths, attn_step=attn)
+                return (jnp.argmax(logits, -1).astype(I32), cache,
+                        lengths + 1), None
+            (tok, cache, _), _ = jax.lax.scan(body, (tok, cache, lengths),
+                                              None, length=steps)
+            return tok, cache
+
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, *scalars).compile()
+        done[span, steps] = compiled, page, n_pages
+        return done[span, steps]
+
+    return compile_
+
+
+@pytest.mark.parametrize("span,steps", [(1, 2), (512, 1)],
+                         ids=["decode", "prefill_chunk"])
+def test_step_programs_hold_no_pool_copies(step_program, span, steps):
+    """Inside the decode and prefill-chunk programs the stacked page
+    pools are written by the aliased writer and read by layer index: no
+    op slices, relays, updates or scatters a pool-sized array — not per
+    layer, and not the whole stack at the program's entry or exit."""
+    import re
+    compiled, page, n_pages = step_program(span, steps)
+    text = compiled.as_text()
+    layer_pool = f"[{n_pages},{HKV},{page},{D}]"
+    stacked = f"[{POOL_LAYERS},{n_pages},{HKV},{page},{D}]"
+    offending = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?)\s([a-z][\w-]*)\(", line)
+        if m and m.group(2) in _POOL_COPIES and (
+                layer_pool in m.group(1) or stacked in m.group(1)):
+            offending.append(line.strip()[:160])
+    assert not offending, "\n".join(offending)
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+
+
+@pytest.mark.parametrize("span", [1, 512], ids=["decode", "prefill_chunk"])
+def test_step_program_temporaries_under_one_layer_pool(step_program, span):
+    """A step's temporaries stay under one layer's pool (the slice,
+    relayout and write-back of pools took 239 MB at one decode step).
+    One decode step: a scan of several also holds a relayout of the
+    q/k/v projection weights that XLA hoists out of it, which is no
+    pool's."""
+    compiled, page, n_pages = step_program(span)
+    pool_bytes = n_pages * HKV * page * D * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, (temp, pool_bytes)
