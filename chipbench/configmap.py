@@ -1,70 +1,64 @@
 """A configuration file, in the published config's key names, mapped
-onto the program's ``ModelConfig``.
+onto the program through the file of its family.
 
-Every key of the file is either mapped onto the program, folded into
-the weights the benchmark draws (``weights.FOLDED``), fixed to the one
-value the program has, or bookkeeping.  Any other key, or a value that
-the program or the plain reference cannot take, is refused, so that a
-file never runs as something other than what it states.
+The family is ``families/<model_type>.py`` (see ``families/__init__``):
+it maps, fixes or folds each of the file's own keys and refuses any
+other.  What every family shares lives here: the lookup, the
+bookkeeping keys, and the rule for the served dtype.  A file never runs
+as something other than what it states.
 """
 
 from __future__ import annotations
 
-import weights
+import importlib
+import os
 
-MAPPED = ("hidden_size", "intermediate_size", "num_attention_heads",
-          "num_key_value_heads", "num_hidden_layers", "vocab_size",
-          "rope_theta", "head_dim", "hidden_act", "tie_word_embeddings",
-          "model_type", "torch_dtype")
-# published values the program has no knob for and computes as these
-FIXED = {"attention_bias": False, "mlp_bias": False}
+FAMILIES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "families")
 BOOKKEEPING = ("source", "deployment", "reduced", "assumed", "folded",
                "init", "serve", "train", "reference", "limits",
                "max_position_embeddings", "name")
-DENSE = ("granite", "llama")        # model types of the dense decoder
-ACT = {"silu": "swiglu"}            # the reference implements SwiGLU
+SHARED = ("model_type", "torch_dtype") + BOOKKEEPING
 DTYPE = ("bfloat16", "float32")
 
 
-def check(c: dict) -> None:
+def families() -> list:
+    """The model types that have a family file."""
+    return sorted(f[:-3] for f in os.listdir(FAMILIES)
+                  if f.endswith(".py") and f != "__init__.py")
+
+
+def family(c: dict):
+    """The family module of file ``c``, by its ``model_type``."""
+    present = families()
+    if c.get("model_type") not in present:
+        raise ValueError(f"model_type {c.get('model_type')!r}: no family "
+                         f"file; the families present are {present}")
+    return importlib.import_module(f"families.{c['model_type']}")
+
+
+def dtype(c: dict):
+    import jax.numpy as jnp
+    return jnp.dtype(c.get("torch_dtype", "bfloat16"))
+
+
+def check(c: dict):
     """Refuse a file with a key or a value that cannot be run as
-    stated."""
-    known = set(MAPPED) | set(weights.FOLDED) | set(FIXED) | set(BOOKKEEPING)
-    extra = sorted(set(c) - known)
-    if extra:
-        raise ValueError(f"configuration keys the benchmark cannot map: "
-                         f"{extra}")
-    for k, v in FIXED.items():
-        if c.get(k, v) != v:
-            raise ValueError(f"{k}={c[k]!r}: the program has only {v!r}")
-    if c.get("model_type") not in DENSE:
-        raise ValueError(f"model_type {c.get('model_type')!r}: the "
-                         f"benchmark maps {DENSE} onto the dense decoder")
-    if c.get("hidden_act", "silu") not in ACT:
-        raise ValueError(f"hidden_act {c['hidden_act']!r}: the reference "
-                         f"implements {sorted(ACT)}")
-    if not c.get("tie_word_embeddings", True):
-        raise ValueError("untied embeddings: the weights and the reference "
-                         "implement a tied LM head only")
+    stated; returns its family."""
+    fam = family(c)
     if c.get("torch_dtype", "bfloat16") not in DTYPE:
         raise ValueError(f"torch_dtype {c['torch_dtype']!r}: served in "
                          f"{DTYPE}")
+    fam.check({k: v for k, v in c.items() if k not in SHARED})
+    return fam
 
 
 def model_config(c: dict):
     """The program's configuration for file ``c``."""
-    import jax.numpy as jnp
-    from repro.models.config import ModelConfig
-    check(c)
-    heads = int(c["num_attention_heads"])
-    return ModelConfig(
-        name=c.get("name", "bench"), family="dense",
-        n_layers=int(c["num_hidden_layers"]),
-        d_model=int(c["hidden_size"]), n_heads=heads,
-        n_kv_heads=int(c["num_key_value_heads"]),
-        head_dim=int(c.get("head_dim") or int(c["hidden_size"]) // heads),
-        d_ff=int(c["intermediate_size"]), vocab=int(c["vocab_size"]),
-        rope_theta=float(c["rope_theta"]),
-        mlp_kind=ACT[c.get("hidden_act", "silu")],
-        tie_embeddings=bool(c.get("tie_word_embeddings", True)),
-        dtype=jnp.dtype(c.get("torch_dtype", "bfloat16")))
+    return check(c).model_config(c)
+
+
+def shape(c: dict):
+    """The work object of file ``c`` that the per-layer readers count
+    with."""
+    return check(c).shape(c)

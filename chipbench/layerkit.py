@@ -1,17 +1,37 @@
-"""What the per-layer readers share: the harness's steps matched to the
-traced ones, and the names by which the trace shows the engine's
-programs and the paged-attention kernel."""
+"""What the per-layer readers share: the loading of a reader, the
+harness's steps matched to the traced ones, and the names by which the
+trace shows the engine's programs and the paged-attention kernel."""
 
 from __future__ import annotations
 
+import importlib.util
+import os
 import re
 
 from serve import percentile  # noqa: F401  (the readers' one percentile)
+
+LAYERS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "layers")
 
 DECODE = re.compile(r"jit__?decode")        # the decode program
 CHUNK = re.compile(r"jit_chunk")            # a prefill-chunk program
 JOIN = re.compile(r"jit_join")              # a whole-prompt prefill
 PAGED_ATTN = re.compile(r"flash_decode")    # the paged-attention kernel
+
+
+def load(path: str):
+    """The reader module in file ``path`` (``layers/<metric>.py``)."""
+    name = os.path.basename(path)[:-len(".py")]
+    spec = importlib.util.spec_from_file_location(
+        "layer_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reader(name: str):
+    """The ``read`` of metric ``name``, for a metric of another cell
+    that is read in the same way."""
+    return load(os.path.join(LAYERS, name + ".py")).read
 
 
 def traced_steps(ctx) -> list:

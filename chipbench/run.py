@@ -20,7 +20,7 @@ No TPU, or fewer chips than the cell asks for: exit 3 and no result.
 from __future__ import annotations
 
 import argparse
-import importlib.util
+import importlib
 import json
 import os
 import shutil
@@ -53,12 +53,9 @@ def cell_metrics(bench: dict, cell: str, kind: str) -> list:
 
 
 def read_layer(bench_dir: str, name: str, ctx: dict):
-    path = os.path.join(bench_dir, "layers", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "layer_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    import layerkit
+    return layerkit.load(os.path.join(bench_dir, "layers",
+                                      name + ".py")).read(ctx)
 
 
 def run_cell(root: str, workload: str, seed: int, seconds: float,
@@ -66,8 +63,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     """Everything after the look for a chip: drive the cell, reduce the
     trace, read the metrics, and build the result line."""
     import jax
+    import configmap
     import trace_reduce
-    import work
 
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -96,7 +93,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     out = {}
     if trace:
         red = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
-        ctx = dict(res, trace=red, shape=work.Shape.from_config(cfg_json),
+        ctx = dict(res, trace=red, shape=configmap.shape(cfg_json),
                    device_kind=dev.device_kind)
         for m in cell_metrics(bench, workload, "per_layer"):
             v = read_layer(bench_dir, m["name"], ctx)

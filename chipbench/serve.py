@@ -10,10 +10,11 @@ the window every request is timed from its due time.  After each step
 the harness waits for the step's device work and reads each request's
 progress (``adapter``): a token counts as seen at the end of the step
 that made it.  Once the window has closed, the harness keeps serving
-until every request due in it has its first token, at most
-``DRAIN_S`` more.  Then it frees the engine, draws a sample of the
-finished requests from the seed, and scores their served tokens with
-the plain reference.
+until every request the run answers for (``counted``: those due in
+the window, or in a backlog those admitted before the close) has its
+first token, at most ``DRAIN_S`` more.  Then it frees the engine,
+draws a sample of the finished requests from the seed, and scores
+their served tokens with the plain reference.
 """
 
 from __future__ import annotations
@@ -97,8 +98,8 @@ def serve_config(sv: dict, cfg):
 def warm_prompts(buckets, prefill_chunk: int, lo: int, hi: int) -> list:
     """One prompt length for each prefill program that prompts of
     ``lo``..``hi`` tokens reach: a whole-prompt join at each bucket up
-    to the prefill chunk, and a chunked prompt whose last chunk has
-    each power-of-two width."""
+    to the prefill chunk, and the shortest chunked prompt from ``lo``
+    whose last chunk has each power-of-two width."""
     out, prev = [], 0
     for b in buckets or ():
         if b > prefill_chunk:
@@ -109,8 +110,10 @@ def warm_prompts(buckets, prefill_chunk: int, lo: int, hi: int) -> list:
         prev = b
     c = 1
     while prefill_chunk and c <= prefill_chunk:
-        n = prefill_chunk + max(c // 2 + 1, 1)
-        if lo <= n <= hi:
+        n = prefill_chunk + c // 2 + 1
+        while n < lo:
+            n += prefill_chunk
+        if n <= hi:
             out.append(n)
         c *= 2
     return sorted(set(out))
@@ -200,6 +203,7 @@ class Server:
         def now() -> float:
             return time.perf_counter() - t_open
 
+        backlog = self.traffic["arrivals"]["process"] == "backlog"
         recs: dict[int, Rec] = {}
         steps: list[Step] = []
         late, queue = [], []
@@ -247,14 +251,14 @@ class Server:
             tn = now()
             if len(queue) == 1 and tn >= seconds:
                 queue.append(adapter.waiting(engine))
-            due_in = [r for r in recs.values() if 0 <= r.due < seconds]
             if tn >= seconds and (trace_span is None or trace_span[1]) \
-                    and (all(r.first is not None for r in due_in)
+                    and (all(r.first is not None
+                             for r in counted(recs, seconds, backlog))
                          or tn >= seconds + DRAIN_S):
                 break
         return {"recs": recs, "steps": steps, "late": late,
                 "t_open": t_open, "t_end": now(), "trace_span": trace_span,
-                "queue": queue, "seconds": seconds,
+                "queue": queue, "seconds": seconds, "backlog": backlog,
                 "compiles_in_window": self.clock.compiles
                 - (compiles_at_open or 0)}
 
@@ -263,12 +267,24 @@ class Server:
         self.engine.shutdown()
 
 
+def counted(recs: dict, seconds: float, backlog: bool) -> list:
+    """The requests a run answers for: those due in the window or, where
+    the traffic is a backlog due before the window opens, those admitted
+    to a slot before it closes.  Each must give its first token, at most
+    ``DRAIN_S`` past the close, and finish ``ok`` if it finishes."""
+    if backlog:
+        return [r for r in recs.values()
+                if r.admit is not None and r.admit < seconds]
+    return [r for r in recs.values() if 0 <= r.due < seconds]
+
+
 def summary(d: dict) -> dict:
     """End-to-end metrics and counts of one ``Server.drive``."""
     recs, seconds = d["recs"], d["seconds"]
     window = [r for r in recs.values() if 0 <= r.due < seconds]
     res = metrics(recs, window, seconds, d["t_end"])
-    never = sum(r.first is None for r in window)
+    answers = counted(recs, seconds, d["backlog"])
+    never = sum(r.first is None for r in answers)
     info = {"requests_due_in_window": len(window),
             "requests_submitted": len(recs),
             "requests_finished": sum(r.output is not None
@@ -280,8 +296,8 @@ def summary(d: dict) -> dict:
             "compiles_in_window": d["compiles_in_window"],
             "steps": len(d["steps"]),
             "served_after_close_s": d["t_end"] - seconds}
-    failed = never + sum(r.status not in (None, "ok") for r in window)
-    return {"metrics": res, "info": info, "attempted": len(window),
+    failed = never + sum(r.status not in (None, "ok") for r in answers)
+    return {"metrics": res, "info": info, "attempted": len(answers),
             "failed": failed, "never": never}
 
 

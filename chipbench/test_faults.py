@@ -54,6 +54,16 @@ def test_broken_path_is_not_correct(root, monkeypatch, fault):
     assert gap["value"] > gap["limit"]
 
 
+@pytest.mark.parametrize("fault", [alter_token, keep_state])
+def test_broken_backlog_is_not_correct(root, monkeypatch, fault):
+    fault(monkeypatch)
+    line = run.run_cell(root, "tiny-backlog", SEED + 1, 3.0, False,
+                        time.perf_counter())
+    assert not line["correct"]
+    gap = line["checks"]["logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
 @pytest.mark.parametrize("seed", [11, 13])
 def test_int8_control_reads_above_the_limit(root, seed):
     import os

@@ -44,6 +44,41 @@ def test_bursty_shared_prefix_cell_from_data_files(root):
     assert line["info"]["compiles_in_window"] == 0
 
 
+def test_backlog_cell_counts_the_requests_it_admitted(root):
+    line = run.run_cell(root, "tiny-backlog", SEED + 3, 3.0, False,
+                        time.perf_counter())
+    assert line["correct"], line["checks"]
+    # nothing is due in the window, so no time to first token
+    assert {"serve_tok_s", "setup_s"} <= set(line["metrics"])
+    assert "ttft_mean_ms" not in line["metrics"]
+    # every request is due before the window opens: a run answers for
+    # those it admitted before the close, and more are left waiting
+    assert line["info"]["requests_due_in_window"] == 0
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert line["info"]["queue_at_close"] > 0
+    assert line["info"]["compiles_in_window"] == 0
+
+
+def test_backlog_failures_count():
+    """In a backlog, a request admitted before the close that ends other
+    than ``ok``, or never gives a token, is failed; one admitted after
+    the close, or never, is not attempted."""
+    recs = {0: rec(-20.0, [(1.0, 1)], out=np.zeros(3, np.int32)),
+            1: rec(-20.0, [(2.0, 1)], out=np.zeros(3, np.int32)),
+            2: rec(-20.0, []),                  # admitted, no token
+            3: rec(-20.0, [(12.0, 1)]),         # admitted after the close
+            4: rec(-20.0, [])}                  # still waiting
+    for r, admit in zip(recs.values(), (0.5, 1.5, 9.0, 10.5, None)):
+        r.admit = admit
+    recs[0].status, recs[1].status = "ok", "failed"
+    d = {"recs": recs, "seconds": 10.0, "t_end": 13.0, "backlog": True,
+         "queue": [5, 1], "late": [0.0], "steps": [],
+         "compiles_in_window": 0}
+    s = serve.summary(d)
+    assert (s["attempted"], s["failed"], s["never"]) == (3, 2, 1)
+    assert serve.summary(dict(d, backlog=False))["attempted"] == 0
+
+
 def test_traced_run_reads_what_the_cpu_has(root):
     line = run.run_cell(root, "tiny-chat", SEED + 1, 5.0, True,
                         time.perf_counter())
@@ -117,6 +152,20 @@ def test_rates_take_the_whole_window_and_tails_every_request():
     # per-token time from the first token seen to the last in the window
     assert sorted([m["tpot_p95_ms"]]) == [pytest.approx(np.percentile(
         [1.5 / 8 * 1e3, 0.1 / 2 * 1e3], 95))]
+
+
+# chat's list is the one from before the warm-up took the clip's low end
+@pytest.mark.parametrize("lo,hi,want", [
+    (64, 3072, [64, 65, 129, 257, 513, 514, 515, 517, 521, 529, 545, 577,
+                641, 769]),
+    (1024, 3584, [1025, 1026, 1027, 1029, 1033, 1041, 1057, 1089, 1153,
+                  1281]),
+    (1024, 1100, [1025, 1026, 1027, 1029, 1033, 1041, 1057, 1089]),
+])
+def test_warm_prompts_reach_every_chunk_width(lo, hi, want):
+    """One prompt for each join bucket and each last-chunk width that
+    prompts of lo..hi tokens reach, each the shortest from lo."""
+    assert serve.warm_prompts([64, 128, 256, 512], 512, lo, hi) == want
 
 
 def test_run_exits_nonzero_without_a_tpu():

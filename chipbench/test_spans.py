@@ -9,12 +9,12 @@ last 46614.209 us together, their three ``engine.readback`` spans
 3893.980 us, and the device ran no operation for 43223.199 us of them.
 """
 
-import importlib.util
 import os
 import shutil
 
 import pytest
 
+import layerkit
 import spans
 import trace_reduce
 
@@ -23,12 +23,7 @@ TRACE = os.path.join(HERE, "testdata", "engine_steps.xplane.pb")
 PARENT_TRACE = os.path.join(HERE, "testdata", "decode_step.xplane.pb")
 
 
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "layers", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+reader = layerkit.reader
 
 
 @pytest.fixture

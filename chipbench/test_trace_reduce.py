@@ -2,11 +2,11 @@
 one TPU v5e: one engine step of granite-3-8b (20 layers, 32 slots), an
 8-step decode program and a 512-token prefill chunk."""
 
-import importlib.util
 import os
 
 import pytest
 
+import layerkit
 import serve
 import trace_reduce
 import work
@@ -59,12 +59,7 @@ def test_union_and_clip():
     assert trace_reduce.clip(u, 1, 5.5) == pytest.approx(2.5)
 
 
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "layers", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+reader = layerkit.reader
 
 
 @pytest.fixture(scope="module")

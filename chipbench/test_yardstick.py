@@ -8,6 +8,7 @@ import jax
 import numpy as np
 import pytest
 
+import configmap
 import peaks
 import traffic
 import work
@@ -108,14 +109,14 @@ def test_matmul_flops_are_twice_the_matmul_parameters():
             n += cfg.vocab * cfg.d_model   # tied LM head, real rows only
         else:
             n += int(np.prod(leaf.shape))
-    s = work.Shape.from_config(c)
+    s = configmap.shape(c)
     assert s.matmul_params == n
     assert work.matmul_flops(s, 37) == 2 * n * 37
 
 
 def test_decode_and_prefill_work():
     c, _, _ = granite_shapes()
-    s = work.Shape.from_config(c)
+    s = configmap.shape(c)
     d = work.decode_step(s, [100, 300])
     kv = 400 * 2 * 8 * 128 * 2 * 20
     assert d["attn_bytes"] == kv + 2 * 2 * 32 * 128 * 2 * 20
@@ -130,7 +131,7 @@ def test_decode_and_prefill_work():
 
 def test_train_step_is_three_forwards():
     c, _, _ = granite_shapes()
-    s = work.Shape.from_config(c)
+    s = configmap.shape(c)
     fwd = work.matmul_flops(s, 2 * 8) + 2 * work.attn_flops(s, range(1, 9))
     assert work.train_step(s, 2, 8) == 3 * fwd
 
@@ -139,44 +140,3 @@ def test_peaks_known_and_unknown():
     assert peaks.peaks_for("TPU v5 lite").bf16_flops == 197e12
     with pytest.raises(ValueError, match="no published peaks"):
         peaks.peaks_for("TPU v9 imaginary")
-
-
-@pytest.mark.parametrize("change", [
-    {"hidden_act": "gelu"}, {"tie_word_embeddings": False},
-    {"attention_bias": True}, {"model_type": "mamba2"},
-    {"sliding_window": 4096}, {"torch_dtype": "float16"}])
-def test_config_keys_mapped_or_refused(change):
-    import configmap
-    with open(SERVE) as f:
-        c = json.load(f)
-    configmap.check(c)
-    with pytest.raises(ValueError):
-        configmap.check(dict(c, **change))
-
-
-def test_granite_file_maps_to_the_published_widths():
-    import configmap
-    from repro.configs.granite_3_8b import CONFIG
-    with open(SERVE) as f:
-        cfg = configmap.model_config(json.load(f))
-    assert dataclasses.replace(cfg, name=CONFIG.name, n_layers=40) == CONFIG
-
-
-def test_folds_are_exact():
-    """The served model with the folds applied is the published one:
-    scaling the residual stream by k turns the program's epsilon into
-    the published one, and each scalar lands on the weights it
-    multiplies."""
-    import math
-    import weights
-    with open(SERVE) as f:
-        c = json.load(f)
-    f = weights.folds(c)
-    k = math.sqrt(weights.PROGRAM_EPS / c["rms_norm_eps"])
-    assert f["embedding"] * math.sqrt(4096) == pytest.approx(
-        c["embedding_multiplier"] * k)
-    assert f["wq"] * 128 ** -0.5 == pytest.approx(c["attention_multiplier"])
-    assert f["wo"] == f["w_down"] == pytest.approx(
-        c["residual_multiplier"] * k)
-    assert f["final_norm"] * f["embedding"] == pytest.approx(
-        1 / c["logits_scaling"])

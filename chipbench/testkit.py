@@ -53,6 +53,12 @@ TINY_TRAIN = dict(
 
 TINY_STEPS = {"driver": "train", "batch": 4, "seq_len": 64}
 
+# a backlog of long prompts, all due before the window opens
+TINY_BACKLOG = dict(
+    TINY_CHAT, arrivals={"process": "backlog", "requests": 96},
+    prompt_tokens={"median": 200, "sigma": 0.4, "min": 100, "max": 400},
+    output_tokens={"median": 8, "sigma": 0.6, "min": 4, "max": 24})
+
 # bursts of sessions that share system prompts, served with the prefix
 # cache on: only data, as a later cell would bring it
 TINY_PREFIX = dict(TINY, serve=dict(TINY["serve"], prefix_cache=True))
@@ -65,9 +71,9 @@ TINY_SESSIONS = dict(
 def make_root(dst: str) -> str:
     """``dst`` becomes a checkout's data: BENCHMARK.json and the
     benchmark's data files, plus config files, traffic files and the
-    entries of cells ``tiny-chat`` and ``tiny-sessions`` (one chip) and
-    ``tiny-train`` (a 2 x 2 mesh) that use them.  No code is added or
-    changed."""
+    entries of cells ``tiny-chat``, ``tiny-sessions`` and
+    ``tiny-backlog`` (one chip) and ``tiny-train`` (a 2 x 2 mesh) that
+    use them.  No code is added or changed."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     path = bench["paths"][0]
@@ -99,6 +105,12 @@ def make_root(dst: str) -> str:
                                "config": "tiny-dense-prefix",
                                "traffic": "tiny_sessions", "chips": 1,
                                "why": "CPU test"})
+    with open(os.path.join(dst, path, "traffic", "tiny_backlog.json"),
+              "w") as f:
+        json.dump(TINY_BACKLOG, f)
+    bench["workloads"].append({"name": "tiny-backlog", "config": "tiny-dense",
+                               "traffic": "tiny_backlog", "chips": 1,
+                               "why": "CPU test"})
     train_file = f"{path}/configs/tiny-dense-train.json"
     with open(os.path.join(dst, train_file), "w") as f:
         json.dump(TINY_TRAIN, f)
@@ -113,9 +125,9 @@ def make_root(dst: str) -> str:
                                "traffic": "tiny_steps", "chips": 4,
                                "why": "CPU test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m and any(w.endswith("chat-steady")
-                                    for w in m["workloads"]):
-            m["workloads"] += ["tiny-chat", "tiny-sessions"]
+        ws = m.get("workloads", [])
+        if any(w.endswith("chat-steady") for w in ws):
+            m["workloads"] += ["tiny-chat", "tiny-sessions", "tiny-backlog"]
     if not any(m["name"] == "train_tok_s" for m in bench["end_to_end"]):
         bench["end_to_end"].append(
             {"name": "train_tok_s", "unit": "tokens/s", "better": "higher",

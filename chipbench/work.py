@@ -5,7 +5,8 @@ of the configuration must compute and move, whichever kernel does it.
 A matrix multiplication of (m, k) by (k, n) is 2mkn FLOPs.  Bytes are
 the least traffic to HBM: each weight once per call, each K/V element
 read once per call, q and o once.  Sizes are the configuration's
-(``Shape``); the element width is that of the served dtype.
+(``Shape``, which a family's ``shape`` builds from its file); the
+element width is that of the served dtype.
 """
 
 from __future__ import annotations
@@ -23,17 +24,6 @@ class Shape:
     d_ff: int
     vocab: int
     elem_bytes: int = 2          # bf16 weights, activations and K/V
-
-    @classmethod
-    def from_config(cls, c: dict) -> "Shape":
-        """From a configuration file's keys (Hugging Face names)."""
-        heads = int(c["num_attention_heads"])
-        d = int(c["hidden_size"])
-        return cls(n_layers=int(c["num_hidden_layers"]), d_model=d,
-                   n_heads=heads, n_kv_heads=int(c["num_key_value_heads"]),
-                   head_dim=int(c.get("head_dim") or d // heads),
-                   d_ff=int(c["intermediate_size"]),
-                   vocab=int(c["vocab_size"]))
 
     @property
     def layer_matmul_params(self) -> int:
